@@ -121,5 +121,8 @@ def main(smoke: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import sys
     main(smoke="--smoke" in sys.argv)

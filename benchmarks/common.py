@@ -11,7 +11,7 @@ import time
 
 from repro.core import EticaCache, EticaConfig, Geometry
 from repro.core.trace import interleave
-from repro.traces import make
+from repro.traces import VM_ADDR_STRIDE, make
 
 GEO = Geometry(num_sets=16, max_ways=32)
 RESIZE = 2_000
@@ -51,8 +51,9 @@ class Timer:
         return self.dt * 1e6
 
 
-def vm_mix(names, reqs=REQS, scale=SCALE):
-    traces = [make(n, reqs, seed=i, addr_offset=i * 10_000_000, scale=scale)
+def vm_mix(names, reqs=REQS, scale=SCALE, seed=0):
+    traces = [make(n, reqs, seed=seed + i, addr_offset=i * VM_ADDR_STRIDE,
+                   scale=scale)
               for i, n in enumerate(names)]
     return interleave(traces, seed=42)
 

@@ -47,5 +47,8 @@ def main(streamed: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import sys
     main(streamed="--streamed" in sys.argv)

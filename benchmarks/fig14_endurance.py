@@ -102,5 +102,8 @@ def main(streamed: bool = False, smoke: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import sys
     main(streamed="--streamed" in sys.argv, smoke="--smoke" in sys.argv)

@@ -41,7 +41,7 @@ from pathlib import Path
 import numpy as np
 
 from repro.core import EticaCache, Trace, make_eci_cache
-from repro.traces import TraceStore, make, make_store
+from repro.traces import VM_ADDR_STRIDE, TraceStore, make, make_store
 
 from .common import GEO, Timer, aggregate_stats as _aggregate
 from .common import etica_config, row, vm_mix
@@ -198,6 +198,24 @@ def streaming_scaling(tmp: str) -> None:
             f"stats_equal={'True' if active == STREAM_PHASES[0] else 'n/a'}")
 
 
+def consolidation_mix(active: int, reqs: int, seed: int = 0) -> Trace:
+    """The sharded consolidation mix: ``active`` VMs cycling through
+    :data:`WORKLOADS`, ``reqs`` requests each."""
+    workloads = (WORKLOADS * ((active + len(WORKLOADS) - 1)
+                              // len(WORKLOADS)))[:active]
+    return vm_mix(workloads, reqs=reqs, seed=seed)
+
+
+def consolidation_cache(active: int, total: int, mesh) -> EticaCache:
+    """The sharded consolidation controller for ``active`` VMs over a
+    ``total``-request mix; ``mesh=None`` is the single-device oracle."""
+    cfg = dataclasses.replace(
+        etica_config("full", dram=12 * active, ssd=25 * active),
+        resize_interval=max(500, total // 3),
+        promo_interval=max(125, total // 12), mesh=mesh)
+    return EticaCache(cfg, active)
+
+
 def sharded_consolidation(smoke: bool = False) -> None:
     """Weak scaling over a VM-axis device mesh: fixed VMs per shard,
     1/2/4/8 shards (capped at the visible device count). Every per-VM
@@ -219,23 +237,14 @@ def sharded_consolidation(smoke: bool = False) -> None:
             "XLA_FLAGS=--xla_force_host_platform_device_count=8 for the "
             "full weak-scaling sweep")
 
-    def build(active: int, total: int, mesh) -> EticaCache:
-        cfg = dataclasses.replace(
-            etica_config("full", dram=12 * active, ssd=25 * active),
-            resize_interval=max(500, total // 3),
-            promo_interval=max(125, total // 12), mesh=mesh)
-        return EticaCache(cfg, active)
-
     agg_at: dict[int, dict] = {}
     for n in shard_counts:
         active = per_shard * n
-        workloads = (WORKLOADS * ((active + len(WORKLOADS) - 1)
-                                  // len(WORKLOADS)))[:active]
-        trace = vm_mix(workloads, reqs=reqs)
+        trace = consolidation_mix(active, reqs)
         mesh = make_vm_mesh(n)
-        build(active, len(trace), mesh).run(trace)   # warm-up compile
+        consolidation_cache(active, len(trace), mesh).run(trace)  # warm-up
         with Timer() as t:
-            res = build(active, len(trace), mesh).run(trace)
+            res = consolidation_cache(active, len(trace), mesh).run(trace)
         agg_at[n] = _aggregate(res)
         hits = np.mean([r.hit_ratio for r in res])
         row(f"fig15/sharded_{n}shards_{active}vms", t.us / len(trace),
@@ -244,10 +253,9 @@ def sharded_consolidation(smoke: bool = False) -> None:
     # bit-identity gate at the largest scale: same VMs on ONE device
     n = shard_counts[-1]
     active = per_shard * n
-    workloads = (WORKLOADS * ((active + len(WORKLOADS) - 1)
-                              // len(WORKLOADS)))[:active]
-    trace = vm_mix(workloads, reqs=reqs)
-    oracle = _aggregate(build(active, len(trace), None).run(trace))
+    trace = consolidation_mix(active, reqs)
+    oracle = _aggregate(consolidation_cache(active, len(trace),
+                                            None).run(trace))
     assert oracle == agg_at[n], (
         f"sharded ({n} shards) and single-device batched runs diverged "
         f"at {active} VMs:\n  sharded: {agg_at[n]}\n  oracle:  {oracle}")
@@ -263,7 +271,7 @@ def main(smoke: bool = False):
         STREAM_PHASES = [32]
         STREAM_REQS_PER_VM = 400
     vm_traces = [make(w, REQS_PER_PHASE * len(PHASES), seed=i,
-                      addr_offset=i * 10_000_000, scale=0.25)
+                      addr_offset=i * VM_ADDR_STRIDE, scale=0.25)
                  for i, w in enumerate(WORKLOADS)]
     scaling_ramp(vm_traces)
     batched_vs_sequential(vm_traces, max(PHASES))
@@ -274,6 +282,9 @@ def main(smoke: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser(

@@ -167,4 +167,7 @@ def maintenance_bench(vm_counts=(8, 32, 128), reqs=256, rounds=3) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

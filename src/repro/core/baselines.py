@@ -114,17 +114,19 @@ def reuse_intensity_metric_ref(geom: Geometry, points: int = 17) -> MetricFn:
 def _use_kernel_sizing() -> bool:
     """Route batched sizing through the Pallas ``sizing_reduction`` path.
 
-    Default: only where Pallas compiles natively (TPU). Override with
-    ``ETICA_SIZING_KERNEL=1`` (forces the kernel path — through the
-    interpreter on CPU, which is how CI parity-checks it) or ``=0``
-    (forces the jnp fallback everywhere).
+    Default: only where Pallas compiles natively (TPU). Off TPU,
+    ``ETICA_SIZING_KERNEL=1`` forces the kernel path (through the
+    interpreter, which is how CI parity-checks it) and ``=0`` keeps the
+    jnp reduction. On a TPU backend the kernel path is always taken and
+    ``=0`` raises rather than quietly swapping in the jnp reference.
     """
-    from repro.kernels import env_flag
+    from repro.kernels import env_flag, on_tpu, refuse_on_tpu
     forced = env_flag("ETICA_SIZING_KERNEL")
-    if forced is not None:
-        return forced
-    import jax
-    return jax.default_backend() == "tpu"
+    if on_tpu():
+        if forced is False:
+            refuse_on_tpu("ETICA_SIZING_KERNEL=0", "the jnp reference")
+        return True
+    return bool(forced)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -161,11 +163,9 @@ class SizingMetric:
         device mesh on either route (shard-local, bit-identical).
         """
         if _use_kernel_sizing():
-            from repro.kernels import use_interpret
             from repro.kernels.reuse_distance import ops as rd_ops
             demands, hits, reads = rd_ops.sizing_metrics_batch(
-                addrs, writes, self.kind, self.grid,
-                interpret=use_interpret(), mesh=mesh)
+                addrs, writes, self.kind, self.grid, mesh=mesh)
         else:
             demands, hits, reads = reuse.sizing_metrics_batch(
                 addrs, writes, self.kind, self.grid, mesh=mesh)

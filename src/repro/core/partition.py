@@ -145,7 +145,7 @@ def _waterfill(alloc, demands, hit_curves, sizes, left, unit):
         best = int(np.argmax(gains))
         if not np.isfinite(gains[best]) or gains[best] <= 0:
             # no VM benefits; still spread capacity up to demand
-            under = np.nonzero(alloc < demands)[0]
+            under = np.nonzero(alloc + unit <= demands)[0]
             if under.size == 0:
                 break
             best = int(under[np.argmax(demands[under] - alloc[under])])

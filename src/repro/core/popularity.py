@@ -206,15 +206,34 @@ def _compact_runs(a: jax.Array, v: jax.Array):
 
     ``a`` must be sorted. Returns (addr, val) where each distinct key
     occupies one slot (its run head position in segment order) and the
-    tail is ``TABLE_EMPTY`` — the scatter-add applies the run's values
-    left to right, which is what keeps the float32 sums identical to the
-    tracker's in-order ``np.add.at`` accumulation.
+    tail is ``TABLE_EMPTY``. Each run's values are summed strictly left
+    to right, ``((v0 + v1) + v2) + ...``, which is what keeps the float32
+    sums identical to the tracker's in-order ``np.add.at`` accumulation.
+    A scatter-add cannot promise that: XLA leaves the order in which
+    colliding updates apply to the backend. Pass ``r`` instead adds the
+    ``r``-th element of every run to its head's sum, so the loop runs
+    as many passes as the longest real run.
     """
     n = a.shape[0]
+    idx = jnp.arange(n, dtype=jnp.int32)
     head = jnp.concatenate([jnp.ones(1, bool), a[1:] != a[:-1]])
     seg = jnp.cumsum(head) - 1
     caddr = jnp.full(n, TABLE_EMPTY, jnp.int32).at[seg].set(a)
-    cval = jnp.zeros(n, jnp.float32).at[seg].add(v)
+    start = jax.lax.cummax(jnp.where(head, idx, 0))    # run head of each
+    longest = jnp.max(jnp.where(a != TABLE_EMPTY, idx - start + 1, 0))
+    vpad = jnp.concatenate([v, jnp.zeros(n, jnp.float32)])
+    spad = jnp.concatenate([start, jnp.full(n, -1, jnp.int32)])
+
+    def add_offset(r, acc):
+        # element r places after each head, where it is still in the run
+        same_run = head & (jax.lax.dynamic_slice(spad, (r,), (n,)) == idx)
+        return acc + jnp.where(same_run,
+                               jax.lax.dynamic_slice(vpad, (r,), (n,)), 0.0)
+
+    acc = jax.lax.fori_loop(0, longest, add_offset,
+                            jnp.zeros(n, jnp.float32))
+    cval = jnp.zeros(n, jnp.float32).at[jnp.where(head, seg, n)].set(
+        acc, mode="drop")
     cval = jnp.where(caddr == TABLE_EMPTY, 0.0, cval)
     return caddr, cval
 

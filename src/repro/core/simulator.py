@@ -847,7 +847,6 @@ def _vm_io(mesh):
 
 @functools.lru_cache(maxsize=None)
 def _two_level_sharded(mesh, mode):
-    from jax.experimental import shard_map
     spec, _ = _vm_io(mesh)
 
     def body(addr, is_write, dram, ssd, ways_dram, ways_ssd, t0):
@@ -856,9 +855,9 @@ def _two_level_sharded(mesh, mode):
                 a, w, dr, ss, wd, ws, mode, tt)
         )(addr, is_write, dram, ssd, ways_dram, ways_ssd, t0)
 
-    return jax.jit(shard_map.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * 7, out_specs=spec,
-        check_rep=False))
+        check_vma=False))
 
 
 def simulate_two_level_sharded(addr, is_write, dram: CacheState,
@@ -877,7 +876,6 @@ def simulate_two_level_sharded(addr, is_write, dram: CacheState,
 
 @functools.lru_cache(maxsize=None)
 def _single_level_sharded(mesh):
-    from jax.experimental import shard_map
     from jax.sharding import PartitionSpec
     spec, _ = _vm_io(mesh)
 
@@ -886,10 +884,10 @@ def _single_level_sharded(mesh):
             _simulate_single_level, in_axes=(0, 0, 0, 0, 0, None, 0)
         )(addr, is_write, state, ways_active, flags, t_cache, t0)
 
-    return jax.jit(shard_map.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec, PartitionSpec(), spec),
-        out_specs=spec, check_rep=False))
+        out_specs=spec, check_vma=False))
 
 
 def simulate_single_level_sharded(addr, is_write, state: CacheState,
@@ -911,7 +909,6 @@ def simulate_single_level_sharded(addr, is_write, state: CacheState,
 
 @functools.lru_cache(maxsize=None)
 def _resize_levels_sharded(mesh):
-    from jax.experimental import shard_map
     spec, _ = _vm_io(mesh)
 
     def body(dram, ssd, old_dram, new_dram, old_ssd, new_ssd):
@@ -919,9 +916,9 @@ def _resize_levels_sharded(mesh):
         ssd, fl_s = jax.vmap(resize)(ssd, old_ssd, new_ssd)
         return dram, ssd, fl_d, fl_s
 
-    return jax.jit(shard_map.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * 6, out_specs=spec,
-        check_rep=False))
+        check_vma=False))
 
 
 def resize_levels_sharded(dram: CacheState, ssd: CacheState, old_dram,
@@ -937,11 +934,10 @@ def resize_levels_sharded(dram: CacheState, ssd: CacheState, old_dram,
 
 @functools.lru_cache(maxsize=None)
 def _resize_batch_sharded(mesh):
-    from jax.experimental import shard_map
     spec, _ = _vm_io(mesh)
-    return jax.jit(shard_map.shard_map(
+    return jax.jit(jax.shard_map(
         lambda st, old, new: jax.vmap(resize)(st, old, new),
-        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_rep=False))
+        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False))
 
 
 def resize_batch_sharded(state: CacheState, old_ways, new_ways, mesh):
@@ -955,7 +951,6 @@ def resize_batch_sharded(state: CacheState, old_ways, new_ways, mesh):
 
 @functools.lru_cache(maxsize=None)
 def _aggregate_stats_sharded(mesh):
-    from jax.experimental import shard_map
     from jax.sharding import PartitionSpec
     spec, _ = _vm_io(mesh)
     ax = mesh.axis_names[0]
@@ -964,9 +959,9 @@ def _aggregate_stats_sharded(mesh):
         return jax.tree_util.tree_map(
             lambda x: jax.lax.psum(jnp.sum(x, axis=0), ax), st)
 
-    return jax.jit(shard_map.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,), out_specs=PartitionSpec(),
-        check_rep=False))
+        check_vma=False))
 
 
 def aggregate_stats_sharded(stats: Stats, mesh) -> Stats:

@@ -10,7 +10,7 @@ from .kernel import paged_decode_attention
 
 
 def decode_attention(q, kv_pool, page_table, lengths, *,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """q: [B, H, D]; kv_pool: (k_pages, v_pages) [NP, PS, Hkv, D]."""
     k_pages, v_pages = kv_pool
     return paged_decode_attention(q, k_pages, v_pages, page_table, lengths,

@@ -3,8 +3,8 @@
 ``evict`` / ``promote`` mirror the contracts of
 ``repro.core.simulator.evict_blocks_batch`` / ``promote_blocks_batch``
 but run the scatters through the Pallas kernels (interpret mode on CPU,
-compiled on TPU — ``interpret=None`` picks by backend, overridable with
-``ETICA_PALLAS_INTERPRET=0|1``).
+compiled on TPU — ``interpret=None`` picks by backend through
+``repro.kernels.use_interpret``).
 
 ``maintenance_interval`` is the whole between-interval maintenance of
 the batched :class:`~repro.core.controller.EticaCache` as ONE jitted
@@ -26,7 +26,7 @@ import numpy as np
 
 from repro.core import popularity as pop
 from repro.core.simulator import CacheState, _next_pow2, _pad_addrs_batch
-from repro.kernels import use_interpret
+from repro.kernels import resolve_interpret
 
 from .kernel import (DEFAULT_QC, DEFAULT_TS, clean_scatter, evict_scatter,
                      promote_scatter)
@@ -59,9 +59,8 @@ def _evict_state(state: CacheState, queue, *, ts, qc, interpret):
                       dirty[:, :s].astype(bool)), flushed
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("ts", "qc", "dedupe", "interpret"))
-def _promote_state(state: CacheState, queue, ways, t, *, ts, qc, dedupe,
+@functools.partial(jax.jit, static_argnames=("ts", "qc", "interpret"))
+def _promote_state(state: CacheState, queue, ways, t, *, ts, qc,
                    interpret):
     v, s, w = state.tags.shape
     ts, s_pad = _tiles(s, ts)
@@ -70,7 +69,7 @@ def _promote_state(state: CacheState, queue, ways, t, *, ts, qc, dedupe,
         _pad_sets(state.lru, s_pad, -1),
         _pad_sets(state.dirty.astype(jnp.int32), s_pad, 0),
         queue, jnp.asarray(ways, jnp.int32), jnp.asarray(t, jnp.int32),
-        num_sets=s, ts=ts, qc=qc, dedupe=dedupe, interpret=interpret)
+        num_sets=s, ts=ts, qc=qc, interpret=interpret)
     return CacheState(tags[:, :s], lru[:, :s],
                       dirty[:, :s].astype(bool)), n
 
@@ -136,8 +135,8 @@ def clean(state: CacheState, ways, quota, *, ts: int = DEFAULT_TS,
     v = state.tags.shape[0]
     ways = jnp.broadcast_to(jnp.asarray(ways, jnp.int32), (v,))
     quota = jnp.broadcast_to(jnp.asarray(quota, jnp.int32), (v,))
-    interpret = use_interpret() if interpret is None else interpret
-    return _clean_state(state, ways, quota, ts=ts, interpret=interpret)
+    return _clean_state(state, ways, quota, ts=ts,
+                        interpret=resolve_interpret(interpret))
 
 
 def _queue_matrix(queues) -> np.ndarray:
@@ -176,28 +175,25 @@ def evict(state: CacheState, queues, *, ts: int = DEFAULT_TS,
         queues = _queue_matrix(queues)
     queues = _pow2_queue(queues)
     qc = min(qc, queues.shape[1])
-    interpret = use_interpret() if interpret is None else interpret
-    return _evict_state(state, queues, ts=ts, qc=qc, interpret=interpret)
+    return _evict_state(state, queues, ts=ts, qc=qc,
+                        interpret=resolve_interpret(interpret))
 
 
 def promote(state: CacheState, queues, ways, t, *, ts: int = DEFAULT_TS,
-            qc: int = DEFAULT_QC, assume_unique: bool = False,
-            interpret: bool | None = None):
+            qc: int = DEFAULT_QC, interpret: bool | None = None):
     """Kernel-backed :func:`repro.core.simulator.promote_blocks_batch`.
 
-    ``ways``/``t`` are ``[V]``. ``assume_unique=True`` skips the
-    in-kernel first-occurrence dedupe (valid when the caller guarantees
-    unique addresses per queue, as the popularity table does). Returns
-    ``(state, promoted[V])``, oracle-identical (``ref.promote_ref``).
+    ``ways``/``t`` are ``[V]``. Queues may hold duplicates and ``-1``
+    padding anywhere (first occurrence wins). Returns ``(state,
+    promoted[V])``, oracle-identical (``ref.promote_ref``).
     """
     if not isinstance(queues, (np.ndarray, jax.Array)):
         queues = _queue_matrix(queues)
     queues = _pow2_queue(queues)
     qc = min(qc, queues.shape[1])
-    interpret = use_interpret() if interpret is None else interpret
     return _promote_state(state, queues, jnp.asarray(ways, jnp.int32),
                           jnp.asarray(t, jnp.int32), ts=ts, qc=qc,
-                          dedupe=not assume_unique, interpret=interpret)
+                          interpret=resolve_interpret(interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -223,10 +219,13 @@ def _maintenance_impl(ssd: CacheState, table: pop.PopularityTable,
     table, drops = pop.table_update(table, waddr, contrib, nval, live, decay)
 
     # 2) eviction queue (bottom-frac of residents when >= 90% full) ->
-    #    evict kernel
+    #    evict kernel. Its valid entries are a prefix of at most
+    #    ceil(frac * S * W) (the table's own f32 rounding), so the
+    #    truncation below drops only -1 padding.
     equeue, eqlen = pop.table_least_popular(table, ssd.tags, ways, alloc,
                                             live, evict_frac)
-    equeue = pop.truncate_queue(equeue, _next_pow2(s * w))
+    ebound = max(int(np.ceil(np.float32(evict_frac) * np.float32(s * w))), 1)
+    equeue = pop.truncate_queue(equeue, _next_pow2(ebound))
     ssd, flushed = _evict_state(ssd, equeue, ts=ts,
                                 qc=min(qc, equeue.shape[1]),
                                 interpret=interpret)
@@ -242,7 +241,7 @@ def _maintenance_impl(ssd: CacheState, table: pop.PopularityTable,
     ssd, promoted = _promote_state(ssd, pqueue, ways,
                                    jnp.asarray(t, jnp.int32), ts=ts,
                                    qc=min(qc, pqueue.shape[1]),
-                                   dedupe=False, interpret=interpret)
+                                   interpret=interpret)
 
     # 4) background cleaner (third stage): age-ranked scan over the
     #    post-promotion dirty blocks, flushing up to `clean_quota` per
@@ -267,8 +266,6 @@ def _maintenance_sharded(mesh, evict_frac, decay, clean_quota, ts, qc,
     block of states/queues. Queue widths depend only on geometry and
     window bucket (never on V), so per-shard shapes line up and the
     compiled HLO is collective-free (asserted by the sharding tests)."""
-    from jax.experimental import shard_map
-
     from repro.launch.mesh import vm_spec
     spec = vm_spec(mesh)
 
@@ -278,9 +275,9 @@ def _maintenance_sharded(mesh, evict_frac, decay, clean_quota, ts, qc,
             evict_frac=evict_frac, decay=decay, clean_quota=clean_quota,
             ts=ts, qc=qc, interpret=interpret)
 
-    return jax.jit(shard_map.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * 8, out_specs=(spec,) * 9,
-        check_rep=False))
+        check_vma=False))
 
 
 def maintenance_interval(ssd: CacheState, table: pop.PopularityTable,
@@ -319,7 +316,7 @@ def maintenance_interval(ssd: CacheState, table: pop.PopularityTable,
     the mesh size; pad with dead ``wlen == 0`` VMs first): the whole
     dispatch runs shard-local with bit-identical per-VM results.
     """
-    interpret = use_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     args = (ssd, table, jnp.asarray(dist, jnp.int32),
             jnp.asarray(served, bool), jnp.asarray(waddr, jnp.int32),
             jnp.asarray(wlen, jnp.int32), jnp.asarray(ways, jnp.int32),

@@ -12,7 +12,7 @@ from .kernel import popularity
 
 
 def block_popularity(addr, dist, served, cache_size, *,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Returns (unique_addrs, scores) for one maintenance window."""
     addr = np.asarray(addr)
     uniq, seg = np.unique(addr, return_inverse=True)

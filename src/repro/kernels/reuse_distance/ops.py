@@ -1,8 +1,9 @@
 """Jitted wrappers: policy-filtered reuse distances via the Pallas kernel.
 
 ``reuse_distances`` mirrors ``repro.core.reuse.pod_distances`` but runs
-the O(N^2) distinct-count through the TPU kernel (interpret=True executes
-the same kernel body on CPU for validation). The prev/next-touch
+the O(N^2) distinct-count through the TPU kernel (compiled on TPU; off
+TPU ``interpret=None`` executes the same kernel body through the Pallas
+interpreter for validation). The prev/next-touch
 bookkeeping stays in regular jnp (sort-based, O(N log N)) — it is not the
 hot spot. ``sizing_reduction`` additionally reduces the kernel-computed
 distance channels into the one-level baselines' sizing metrics.
@@ -36,12 +37,13 @@ import numpy as np
 
 from repro.core.policies import Policy
 from repro.core import reuse as core_reuse
+from repro.kernels import resolve_interpret
 from .kernel import count_between
 
 
 def reuse_distances(addr, is_write, policy: Policy, *,
                     sizing_reads_only: bool = True,
-                    interpret: bool = True,
+                    interpret: bool | None = None,
                     ti: int = 256, tj: int = 512):
     """DistResult with the pairwise count computed by the Pallas kernel.
 
@@ -83,7 +85,8 @@ def reuse_distances(addr, is_write, policy: Policy, *,
 
 def sizing_reduction(addr, is_write, kind: str, grid, *, n_valid=None,
                      with_reads: bool = False,
-                     interpret: bool = True, ti: int = 256, tj: int = 512):
+                     interpret: bool | None = None, ti: int = 256,
+                     tj: int = 512):
     """``(demand, hit_counts[G])`` for one trace, kernel-backed.
 
     The kernel analogue of the batched jnp sizing path: the O(N^2)
@@ -157,7 +160,7 @@ def _sizing_sharded(mesh, amat, wmat, nvec, grid, kind, interpret, ti, tj):
 
 
 def sizing_metrics_batch(addrs, writes, kind: str, grid, *,
-                         interpret: bool = True, ti: int = 256,
+                         interpret: bool | None = None, ti: int = 256,
                          tj: int = 512, mesh=None):
     """Kernel-backed ``core.reuse.sizing_metrics_batch``: same ragged
     contract and ``(demands, hit_counts, read_counts)`` returns, but the
@@ -173,6 +176,7 @@ def sizing_metrics_batch(addrs, writes, kind: str, grid, *,
     if kind not in core_reuse.SIZING_KINDS:
         raise ValueError(
             f"kind must be one of {core_reuse.SIZING_KINDS}, got {kind!r}")
+    interpret = resolve_interpret(interpret)
     lens = [int(np.shape(a)[0]) for a in addrs]
     grid = np.asarray(grid, np.int32)
     demands = np.zeros(len(lens), np.int64)

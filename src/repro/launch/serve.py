@@ -37,6 +37,7 @@ import numpy as np
 
 from repro import configs
 from repro.kernels.decode_attention.ops import decode_attention
+from repro.kernels.decode_attention.ref import paged_decode_ref
 from repro.kvcache import GlobalLRUManager, TwoTierConfig, TwoTierKVManager
 from repro.models import model as M
 from repro.traces import (SESSION_ACTIVATE, SESSION_APPEND, SESSION_END,
@@ -78,9 +79,12 @@ def kv_page_bank(cfg, kv_cfg: TwoTierConfig, bank: int, seed: int):
 
 
 def run_events(mgr, trace, k_bank, v_bank, *, decode_every: int = 0,
-               seed: int = 0):
+               seed: int = 0, check_ref: bool = False):
     """Replay a SessionTrace through a manager; optionally run a real
-    paged-attention decode step every ``decode_every``-th activation."""
+    paged-attention decode step every ``decode_every``-th activation.
+    ``check_ref`` also compares each decode step with the pure-jnp
+    oracle (``decode_attention/ref.py``, f32 matmuls) and raises on a
+    mismatch."""
     rng = np.random.default_rng(seed)
     bank = k_bank.shape[0]
     n_act = 0
@@ -98,10 +102,16 @@ def run_events(mgr, trace, k_bank, v_bank, *, decode_every: int = 0,
                 h, d = mgr.cfg.num_kv_heads, mgr.cfg.head_dim
                 q = jnp.asarray(rng.normal(size=(1, h, d)), jnp.float32)
                 lengths = jnp.asarray([mgr.sessions[sid].length], jnp.int32)
-                out = decode_attention(
-                    q, (mgr.k_pool[0], mgr.v_pool[0]),
-                    jnp.asarray(pt[None, :]), lengths)
+                pool = (mgr.k_pool[0], mgr.v_pool[0])
+                table = jnp.asarray(pt[None, :])
+                out = decode_attention(q, pool, table, lengths)
                 assert bool(jnp.all(jnp.isfinite(out)))
+                if check_ref:
+                    with jax.default_matmul_precision("highest"):
+                        want = paged_decode_ref(q, *pool, table, lengths)
+                    np.testing.assert_allclose(np.asarray(out),
+                                               np.asarray(want),
+                                               rtol=1e-4, atol=1e-4)
             mgr.deactivate(sid)
         elif kind == SESSION_END:
             mgr.end_session(sid)

@@ -15,7 +15,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -39,8 +38,8 @@ def compressed_psum(grads, mesh, axis_names=("data",)):
     specs = jax.tree_util.tree_map(lambda _: P(), grads)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(specs,), out_specs=specs,
-        check_rep=False)
+        jax.shard_map, mesh=mesh, in_specs=(specs,), out_specs=specs,
+        check_vma=False)
     def reduce_fn(g):
         def one(x):
             q, scale = quantize_int8(x)

@@ -130,9 +130,21 @@ def generate(spec: WorkloadSpec, n: int, seed: int = 0,
                     j = prev_w[-1 - rng.integers(0, min(8, prev_w.size))]
                     addr[i] = addr[j]
 
-    return Trace(addr=(addr + addr_offset).astype(np.int32),
-                 is_write=is_write,
+    return Trace(addr=_to_int32(addr, addr_offset), is_write=is_write,
                  size=_draw_sizes(spec, n, rng))
+
+
+def _to_int32(addr: np.ndarray, addr_offset: int) -> np.ndarray:
+    """``addr + addr_offset`` as int32 block addresses, refusing any
+    that would not fit: a wrapped address turns negative, which the
+    datapath treats as padding and silently skips."""
+    out = np.asarray(addr, np.int64) + int(addr_offset)
+    if out.size and (out.min() < 0 or out.max() >= 2**31):
+        raise ValueError(
+            f"block addresses [{out.min()}, {out.max()}] (offset "
+            f"{addr_offset}) do not fit int32 [0, 2^31) — use a smaller "
+            f"per-VM address stride")
+    return out.astype(np.int32)
 
 
 def _draw_sizes(spec: WorkloadSpec, n: int,
@@ -194,8 +206,8 @@ def _generate_seq_interleaved(spec: WorkloadSpec, n: int, seed: int,
     addr = np.concatenate(out_a)
     is_write = np.concatenate(out_w)
     size = np.concatenate(out_s) if rnd.size is not None else None
-    return Trace(addr=(addr + addr_offset).astype(np.int32),
-                 is_write=is_write, size=size)
+    return Trace(addr=_to_int32(addr, addr_offset), is_write=is_write,
+                 size=size)
 
 
 # -- named families ---------------------------------------------------------
@@ -426,8 +438,16 @@ def generate_to_store(path, spec: WorkloadSpec, n: int, seed: int = 0,
                                  shard_size=shard_size or DEFAULT_SHARD_SIZE)
 
 
+# per-VM address stride of the consolidated mixes: 2^20 blocks apart
+# holds every generator's address range (working set plus one-shot
+# cold/burst/scan addresses at the request counts used here) and keeps
+# 1024 VMs below 2^30, the bound the reuse-distance padding and the
+# popularity table's empty-slot sentinel reserve
+VM_ADDR_STRIDE = 2**20
+
+
 def make_store(path, workloads: list[str], reqs_per_vm: int, seed: int = 0,
-               scale: float = 1.0, addr_stride: int = 10_000_000,
+               scale: float = 1.0, addr_stride: int = VM_ADDR_STRIDE,
                interleave_seed: int = 42, shard_size: int | None = None):
     """Generate a consolidated multi-VM mix straight into a TraceStore.
 

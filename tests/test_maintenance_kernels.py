@@ -106,6 +106,9 @@ def test_promote_duplicates_first_occurrence_wins():
 
 
 def test_promote_assume_unique_matches_dedupe_on_unique_queues():
+    """Repeating every entry of a unique queue changes nothing: the
+    in-order drain lets each first occurrence win without a dedupe
+    pass."""
     rng = np.random.default_rng(6)
     st_ = _random_state(rng, 3, 5, 4)
     queues = [rng.permutation(60)[: int(rng.integers(0, 25))].astype(np.int32)
@@ -113,8 +116,8 @@ def test_promote_assume_unique_matches_dedupe_on_unique_queues():
     ways = rng.integers(0, 5, 3).astype(np.int32)
     t = np.array([1, 2, 3], np.int32)
     a, na = ops.promote(st_, queues, ways, t, interpret=True)
-    b, nb = ops.promote(st_, queues, ways, t, assume_unique=True,
-                        interpret=True)
+    doubled = [np.repeat(q, 2) for q in queues]
+    b, nb = ops.promote(st_, doubled, ways, t, interpret=True)
     _assert_state(a, np.asarray(b.tags), np.asarray(b.lru),
                   np.asarray(b.dirty, np.int32), "assume_unique")
     assert np.array_equal(np.asarray(na), np.asarray(nb))

@@ -19,8 +19,9 @@ import pytest
 from repro.core import (EticaCache, EticaConfig, Geometry, Policy, Trace,
                         interleave, make_eci_cache, pad_batch, split_by_vm)
 from repro.core.baselines import eci_policy
-from repro.traces import (StreamingTraceSource, TraceStore, make, make_store,
-                          parse_blktrace, parse_msr_csv, window_source)
+from repro.traces import (VM_ADDR_STRIDE, StreamingTraceSource, TraceStore,
+                          make, make_store, parse_blktrace, parse_msr_csv,
+                          window_source)
 from repro.traces.store import main as store_cli
 
 GEO = Geometry(num_sets=8, max_ways=16)
@@ -28,7 +29,7 @@ GEO = Geometry(num_sets=8, max_ways=16)
 
 def _mixed_trace(num_vms=3, reqs=2000, workloads=("hm_1", "usr_0", "web_3")):
     return interleave(
-        [make(n, reqs, seed=i, addr_offset=i * 10_000_000, scale=0.25)
+        [make(n, reqs, seed=i, addr_offset=i * VM_ADDR_STRIDE, scale=0.25)
          for i, n in enumerate(workloads[:num_vms])], seed=0)
 
 
@@ -352,3 +353,25 @@ def test_eci_policy_chooser_batch_matches_ref(seed):
     # threshold boundary: ratio exactly at the threshold picks RO
     assert chooser.batch([4], [5]) == [Policy.RO]      # 0.8 >= 0.8
     assert chooser.batch([3], [5]) == [Policy.WB]
+
+
+def test_generators_refuse_addresses_beyond_int32():
+    """An address offset that would wrap int32 is refused instead of
+    producing negative (padding) addresses the datapath skips."""
+    with pytest.raises(ValueError, match="int32"):
+        make("hm_1", 100, seed=0, addr_offset=2**31 - 50)
+    with pytest.raises(ValueError, match="int32"):
+        make("scan_mix", 400, seed=0, addr_offset=2**31 - 50)
+
+
+def test_consolidation_mix_of_1024_vms_has_no_negative_addresses():
+    """The benchmark mix holds 1024 VMs at the consolidation stride: every
+    address is a real (non-negative) block below the 2^30 bound."""
+    import benchmarks.common as bench
+    from benchmarks.fig15_vm_scaling import WORKLOADS
+
+    names = (WORKLOADS * (1024 // len(WORKLOADS) + 1))[:1024]
+    mix = bench.vm_mix(names, reqs=20)
+    addr = np.asarray(mix.addr)
+    assert addr.min() >= 0 and addr.max() < 2**30
+    assert np.unique(np.asarray(mix.vm)).size == 1024
