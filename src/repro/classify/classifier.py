@@ -25,6 +25,8 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.runtime.telemetry import sync
+
 from .rules import ClassRule, IOClass, RulePlan, compile_rules, \
     classify_block, classify_ref
 
@@ -85,13 +87,10 @@ class Classifier:
             amat[i, :k] = np.asarray(sub.addr, np.int32)
             wmat[i, :k] = np.asarray(sub.is_write)
             smat[i, :k] = sub.sizes()
-        cls, ce, cl = classify_block(amat, wmat, smat, lens,
-                                     np.asarray(carry_end, np.int32),
-                                     np.asarray(carry_len, np.int32),
-                                     self.plan)
-        cls = np.asarray(cls)
-        return ([cls[i, :lens[i]] for i in range(v)],
-                np.asarray(ce), np.asarray(cl))
+        cls, ce, cl = sync("classify", classify_block(
+            amat, wmat, smat, lens, np.asarray(carry_end, np.int32),
+            np.asarray(carry_len, np.int32), self.plan))
+        return [cls[i, :lens[i]] for i in range(v)], ce, cl
 
     def classify_trace_ref(self, trace, carry_end: int = -1,
                            carry_len: int = 0):

@@ -38,6 +38,8 @@ from typing import Callable
 import jax
 import numpy as np
 
+from repro.runtime.telemetry import TelemetryRecorder, span, sync
+
 from . import popularity as pop
 from .partition import partition as _partition
 from . import reuse, simulator
@@ -316,7 +318,6 @@ class EticaCache:
         if cfg.telemetry is not None:
             self.telemetry = cfg.telemetry
         else:
-            from repro.runtime.telemetry import TelemetryRecorder
             self.telemetry = TelemetryRecorder()
         self._m_promoted = np.zeros(num_vms, np.int64)
         self._m_evicted = np.zeros(num_vms, np.int64)
@@ -381,32 +382,33 @@ class EticaCache:
 
     # -- sizing -----------------------------------------------------------
     def _size_level(self, subs: list[Trace], policy: Policy, geom: Geometry,
-                    capacity: int, cls_subs: list[np.ndarray] | None = None):
+                    capacity: int, cls_subs: list[np.ndarray] | None = None,
+                    *, level: str):
         grid = _mrc_grid(geom, self.cfg.mrc_points)
-        demands = np.zeros(self.num_vms, np.int64)
-        curves = np.zeros((self.num_vms, grid.size))
-        addrs = [np.asarray(s.addr) for s in subs]
-        writes = [np.asarray(s.is_write) for s in subs]
-        wts = None
-        if cls_subs is not None:
-            # per-class sizing weights: weight-0 (bypass) requests never
-            # reach the cache, so they are cut from the sizing sub-traces;
-            # the rest weight the hit curves per class
-            cw = self.classifier.weights
-            wts = []
-            for v, cs in enumerate(cls_subs):
-                w_req = cw[cs]
-                keep = w_req > 0
-                if not keep.all():
-                    addrs[v] = addrs[v][keep]
-                    writes[v] = writes[v][keep]
-                    w_req = w_req[keep]
-                wts.append(w_req)
-        if self.cfg.batched:
-            # all VMs' POD decompositions in one vmapped dispatch (with a
-            # mesh: dead-VM rows pad to the sharded row count and each
-            # device decomposes its own block)
-            with self.telemetry.span("sizing") as sp:
+        with self.telemetry.span("sizing", level=level) as sp:
+            demands = np.zeros(self.num_vms, np.int64)
+            curves = np.zeros((self.num_vms, grid.size))
+            addrs = [np.asarray(s.addr) for s in subs]
+            writes = [np.asarray(s.is_write) for s in subs]
+            wts = None
+            if cls_subs is not None:
+                # per-class sizing weights: weight-0 (bypass) requests
+                # never reach the cache, so they are cut from the sizing
+                # sub-traces; the rest weight the hit curves per class
+                cw = self.classifier.weights
+                wts = []
+                for v, cs in enumerate(cls_subs):
+                    w_req = cw[cs]
+                    keep = w_req > 0
+                    if not keep.all():
+                        addrs[v] = addrs[v][keep]
+                        writes[v] = writes[v][keep]
+                        w_req = w_req[keep]
+                    wts.append(w_req)
+            if self.cfg.batched:
+                # all VMs' POD decompositions in one vmapped dispatch
+                # (with a mesh: dead-VM rows pad to the sharded row count
+                # and each device decomposes its own block)
                 if self.cfg.mesh is not None:
                     pad = self._rows - self.num_vms
                     dists = reuse.pod_distances_batch(
@@ -416,26 +418,30 @@ class EticaCache:
                 else:
                     dists = reuse.pod_distances_batch(addrs, writes, policy)
                 sp.ready(dists)
-        else:
-            dists = [reuse.pod_distances(a, w, policy) if a.size else None
-                     for a, w in zip(addrs, writes)]
-        for v, r in enumerate(dists):
-            if r is None:
-                continue
-            demands[v] = min(reuse.demand_blocks(int(r.max)), geom.capacity)
-            if wts is None:
-                hits = reuse.hit_counts_at_sizes(r.dist, r.served, grid)
-                curves[v] = np.asarray(hits, np.float64) / max(len(subs[v]), 1)
             else:
-                hits = reuse.hit_counts_at_sizes_weighted(
-                    r.dist, r.served, grid, wts[v])
-                curves[v] = hits / max(wts[v].sum(), 1)
-        res = _partition(demands, curves, grid, capacity)
-        if wts is None:
-            counts = np.array([len(s) for s in subs], np.float64)
-        else:
-            counts = np.array([w.sum() for w in wts], np.float64)
-        alloc = _expand_to_capacity(res.alloc, counts, capacity, geom)
+                dists = [reuse.pod_distances(a, w, policy) if a.size
+                         else None for a, w in zip(addrs, writes)]
+            for v, r in enumerate(dists):
+                if r is None:
+                    continue
+                demands[v] = min(
+                    reuse.demand_blocks(int(sync("demand", r.max))),
+                    geom.capacity)
+                if wts is None:
+                    hits = reuse.hit_counts_at_sizes(r.dist, r.served, grid)
+                    curves[v] = (np.asarray(hits, np.float64)
+                                 / max(len(subs[v]), 1))
+                else:
+                    hits = reuse.hit_counts_at_sizes_weighted(
+                        r.dist, r.served, grid, wts[v])
+                    curves[v] = hits / max(wts[v].sum(), 1)
+        with span("partition"):
+            res = _partition(demands, curves, grid, capacity)
+            if wts is None:
+                counts = np.array([len(s) for s in subs], np.float64)
+            else:
+                counts = np.array([w.sum() for w in wts], np.float64)
+            alloc = _expand_to_capacity(res.alloc, counts, capacity, geom)
         return alloc, demands, dists
 
     # -- maintenance --------------------------------------------------------
@@ -528,7 +534,8 @@ class EticaCache:
         if self.cfg.fused_maintenance:
             self._maintain_fused(chunks)
         else:
-            self._maintain_staged(chunks)
+            with span("maintenance"):
+                self._maintain_staged(chunks)
 
     def _maintain_fused(self, chunks: list[Trace | None]) -> None:
         """One fused jitted dispatch for the whole interval's maintenance
@@ -546,21 +553,23 @@ class EticaCache:
         live = [v for v, n in enumerate(lens) if n > 0]
         if not live:
             return
-        # batched TRD decomposition (same bucketing as trd_distances_batch)
-        # — results stay on device and feed the fused dispatch directly.
-        # ALL VMs ride as rows (idle ones zero-length) so the fused
-        # executable is keyed only by the window bucket, not by which
-        # subset of VMs is live. With a mesh both the decomposition and
-        # the fused maintenance run one row block per device.
-        amat, wmat = reuse._pad_rows(addrs, writes, list(range(self._rows)),
-                                     lens)
-        if cfg.mesh is not None:
-            r = reuse._decompose_sharded(cfg.mesh, amat, wmat, Policy.WB,
-                                         False, 256)
-        else:
-            r = reuse._decompose_vmapped(amat, wmat, policy=Policy.WB,
-                                         sizing_reads_only=False, chunk=256)
         with self.telemetry.span("maintenance") as sp:
+            # batched TRD decomposition (same bucketing as
+            # trd_distances_batch) — results stay on device and feed the
+            # fused dispatch directly. ALL VMs ride as rows (idle ones
+            # zero-length) so the fused executable is keyed only by the
+            # window bucket, not by which subset of VMs is live. With a
+            # mesh both the decomposition and the fused maintenance run
+            # one row block per device.
+            amat, wmat = reuse._pad_rows(addrs, writes,
+                                         list(range(self._rows)), lens)
+            if cfg.mesh is not None:
+                r = reuse._decompose_sharded(cfg.mesh, amat, wmat,
+                                             Policy.WB, False, 256)
+            else:
+                r = reuse._decompose_vmapped(amat, wmat, policy=Policy.WB,
+                                             sizing_reads_only=False,
+                                             chunk=256)
             (self.ssd, self.pop_table, flushed, promoted, eqlen, pqlen,
              pdrops, cleaned, dirty_left) = maint_ops.maintenance_interval(
                     self.ssd, self.pop_table, r.dist, r.served, amat,
@@ -568,51 +577,59 @@ class EticaCache:
                     evict_frac=cfg.evict_frac, decay=cfg.popularity_decay,
                     clean_quota=cfg.clean_quota, mesh=cfg.mesh)
             sp.ready((self.ssd, self.pop_table, flushed))
-        # ONE host transfer for all per-VM counters — the cleaner's two
-        # vectors ride the sync the interval already paid for
-        flushed, promoted, eqlen, pqlen, pdrops, cleaned, dirty_left = \
-            jax.device_get((flushed, promoted, eqlen, pqlen, pdrops,
-                            cleaned, dirty_left))
-        # drop the dead-VM pad rows (all-zero: wlen == 0 skips them)
-        flushed, promoted, eqlen, pqlen, pdrops, cleaned, dirty_left = (
-            np.asarray(x)[: self.num_vms]
-            for x in (flushed, promoted, eqlen, pqlen, pdrops, cleaned,
-                      dirty_left))
-        for v in live:
-            if pdrops[v]:
-                # merge-overflow: popularity entries pushed past the [V, K]
-                # table's capacity this interval (device-table path only —
-                # the host trackers are effectively unbounded)
-                self.stats[v]["pop_drops"] = (
-                    self.stats[v].get("pop_drops", 0.0) + int(pdrops[v]))
-            if eqlen[v]:
-                self.stats[v]["disk_writes"] = (
-                    self.stats[v].get("disk_writes", 0.0) + int(flushed[v]))
-                self.stats[v]["evict_flushes"] = (
-                    self.stats[v].get("evict_flushes", 0.0)
-                    + int(flushed[v]))
-            if pqlen[v]:
-                # each promotion = 1 disk read + 1 SSD write (endurance)
-                self.stats[v]["cache_writes_l2"] = (
-                    self.stats[v].get("cache_writes_l2", 0.0)
-                    + int(promoted[v]))
-                self.stats[v]["disk_reads"] = (
-                    self.stats[v].get("disk_reads", 0.0) + int(promoted[v]))
+            # ONE host transfer for all per-VM counters — the cleaner's
+            # two vectors ride the sync the interval already paid for
+            (flushed, promoted, eqlen, pqlen, pdrops, cleaned,
+             dirty_left) = sync("maintenance", (
+                 flushed, promoted, eqlen, pqlen, pdrops, cleaned,
+                 dirty_left))
+            # drop the dead-VM pad rows (all-zero: wlen == 0 skips them)
+            (flushed, promoted, eqlen, pqlen, pdrops, cleaned,
+             dirty_left) = (np.asarray(x)[: self.num_vms]
+                            for x in (flushed, promoted, eqlen, pqlen,
+                                      pdrops, cleaned, dirty_left))
+            for v in live:
+                if pdrops[v]:
+                    # merge-overflow: popularity entries pushed past the
+                    # [V, K] table's capacity this interval (device-table
+                    # path only — the host trackers are effectively
+                    # unbounded)
+                    self.stats[v]["pop_drops"] = (
+                        self.stats[v].get("pop_drops", 0.0)
+                        + int(pdrops[v]))
+                if eqlen[v]:
+                    self.stats[v]["disk_writes"] = (
+                        self.stats[v].get("disk_writes", 0.0)
+                        + int(flushed[v]))
+                    self.stats[v]["evict_flushes"] = (
+                        self.stats[v].get("evict_flushes", 0.0)
+                        + int(flushed[v]))
+                if pqlen[v]:
+                    # each promotion = 1 disk read + 1 SSD write
+                    # (endurance)
+                    self.stats[v]["cache_writes_l2"] = (
+                        self.stats[v].get("cache_writes_l2", 0.0)
+                        + int(promoted[v]))
+                    self.stats[v]["disk_reads"] = (
+                        self.stats[v].get("disk_reads", 0.0)
+                        + int(promoted[v]))
+                if cfg.clean_quota > 0:
+                    self.stats[v]["flushes"] = (
+                        self.stats[v].get("flushes", 0.0) + int(cleaned[v]))
+                    self.stats[v]["disk_writes"] = (
+                        self.stats[v].get("disk_writes", 0.0)
+                        + int(cleaned[v]))
+                    self.stats[v]["dirty_resident"] = float(dirty_left[v])
+            # same masking as the stats credits above: the kernel outputs
+            # are only meaningful where the corresponding queue was
+            # non-empty
+            self._m_promoted += np.where(np.asarray(pqlen) > 0,
+                                         np.asarray(promoted, np.int64), 0)
+            self._m_evicted += np.asarray(eqlen, np.int64)
             if cfg.clean_quota > 0:
-                self.stats[v]["flushes"] = (
-                    self.stats[v].get("flushes", 0.0) + int(cleaned[v]))
-                self.stats[v]["disk_writes"] = (
-                    self.stats[v].get("disk_writes", 0.0) + int(cleaned[v]))
-                self.stats[v]["dirty_resident"] = float(dirty_left[v])
-        # same masking as the stats credits above: the kernel outputs are
-        # only meaningful where the corresponding queue was non-empty
-        self._m_promoted += np.where(np.asarray(pqlen) > 0,
-                                     np.asarray(promoted, np.int64), 0)
-        self._m_evicted += np.asarray(eqlen, np.int64)
-        if cfg.clean_quota > 0:
-            self._m_cleaned += np.asarray(cleaned, np.int64)
-            self._m_dirty = np.asarray(dirty_left, np.int64)
-            self._m_clean_ran = True
+                self._m_cleaned += np.asarray(cleaned, np.int64)
+                self._m_dirty = np.asarray(dirty_left, np.int64)
+                self._m_clean_ran = True
 
     def _maintain_staged(self, chunks: list[Trace | None]) -> None:
         """Staged batched maintenance (host trackers + separate vmapped
@@ -638,13 +655,13 @@ class EticaCache:
             dmat[i, : lens[i]] = rs[i].dist
             smat[i, : lens[i]] = rs[i].served
             cs[i] = max(self._alloc_blocks(v), 1)
-        cmat = np.asarray(pop.contributions(dmat, smat, cs))
+        cmat = sync("contrib", pop.contributions(dmat, smat, cs))
         for i, v in enumerate(live):
             self.trackers[v].update(np.asarray(chunks[v].addr),
                                     cmat[i, : lens[i]])
 
         nothing = np.empty(0, np.int64)
-        tags_np = np.asarray(self.ssd.tags)
+        tags_np = sync("tags", self.ssd.tags)
         evict_qs = [nothing] * self.num_vms
         for v in live:
             res = self._residents(tags_np, v)
@@ -656,7 +673,7 @@ class EticaCache:
                                           np.int64)
             self.ssd, flushed = simulator.evict_blocks_batch(
                 self.ssd, evict_qs)
-            flushed = np.asarray(flushed)
+            flushed = sync("evict", flushed)
             for v in live:
                 if evict_qs[v].size:
                     self.stats[v]["disk_writes"] = (
@@ -665,7 +682,7 @@ class EticaCache:
                     self.stats[v]["evict_flushes"] = (
                         self.stats[v].get("evict_flushes", 0.0)
                         + int(flushed[v]))
-            tags_np = np.asarray(self.ssd.tags)
+            tags_np = sync("tags", self.ssd.tags)
 
         promo_qs = [nothing] * self.num_vms
         for v in live:
@@ -676,7 +693,7 @@ class EticaCache:
         if any(q.size for q in promo_qs):
             self.ssd, n = simulator.promote_blocks_batch(
                 self.ssd, promo_qs, self.ways_ssd, self.t)
-            n = np.asarray(n)
+            n = sync("promote", n)
             for v in live:
                 if promo_qs[v].size:
                     self._m_promoted[v] += int(n[v])
@@ -693,7 +710,7 @@ class EticaCache:
             quota[live] = cfg.clean_quota
             self.ssd, cleaned, dirty_left = simulator.clean_batch(
                 self.ssd, self.ways_ssd, quota)
-            cleaned, dirty_left = jax.device_get((cleaned, dirty_left))
+            cleaned, dirty_left = sync("clean", (cleaned, dirty_left))
             for v in live:
                 self.stats[v]["flushes"] = (
                     self.stats[v].get("flushes", 0.0) + int(cleaned[v]))
@@ -732,15 +749,16 @@ class EticaCache:
                         a, w, cmat, self.dram, self.ssd, self.ways_dram,
                         self.ways_ssd, self._byp, self._lo_d, self._hi_d,
                         self._lo_s, self._hi_s, mode=cfg.mode, t0=self.t)
-                ch, cm = jax.device_get((ch, cm))
+                ch, cm = sync("classes", (ch, cm))
                 self.cls_hits += np.asarray(ch, np.int64)
                 self.cls_miss += np.asarray(cm, np.int64)
             sp.ready(st)
-        self.t = np.asarray(t_end)
-        st = jax.device_get(st)
-        for v, chunk in enumerate(chunks):
-            if chunk is not None:
-                _acc(self.stats[v], Stats(*[f[v] for f in st]))
+        with span("stats"):
+            self.t = sync("clock", t_end)
+            st = sync("stats", st)
+            for v, chunk in enumerate(chunks):
+                if chunk is not None:
+                    _acc(self.stats[v], Stats(*[f[v] for f in st]))
 
     def _run_chunk_sequential(self, chunks: list[Trace | None],
                               cls_subs: list[np.ndarray] | None = None,
@@ -776,49 +794,39 @@ class EticaCache:
             _acc(self.stats[v], st)
 
     # -- main loop ----------------------------------------------------------
-    def run(self, trace) -> list[VMResult]:
-        """Drive the controller over a whole trace.
-
-        ``trace`` may be an in-memory :class:`Trace`, an on-disk
-        :class:`repro.traces.store.TraceStore`, or a pre-built
-        :class:`repro.traces.stream.StreamingTraceSource` — all three
-        produce bit-identical results; the store/stream paths never hold
-        more than one resize window (plus the in-flight ``[V, chunk]``
-        blocks) in host memory."""
+    def _run_window(self, win, alloc_hist: list[list[int]]) -> None:
+        """One resize window: size, partition and resize both levels, then
+        simulate and maintain it promotion interval by interval."""
         cfg = self.cfg
         gd, gs = cfg.geometry_dram, cfg.geometry_ssd
-        alloc_hist = [[] for _ in range(self.num_vms)]
-        source = _window_source(trace, self.num_vms, cfg.resize_interval,
-                                cfg.promo_interval, cfg.prefetch,
-                                cfg.prefetch_depth,
-                                self._rows - self.num_vms, self._sharding)
-        for win in source.windows():
-            subs = win.subs
-            # 0) IO classification: one fused dispatch per window, the
-            # sequential-run carry threaded across windows per VM
-            cls_subs = None
-            if self.classifier is not None:
-                cls_subs, self._cls_end, self._cls_len = \
-                    self.classifier.classify_subs(subs, self._cls_end,
-                                                  self._cls_len)
-            # 1) POD sizing + PPC partitioning at both levels (§4.3)
-            alloc_d, dem_d, _ = self._size_level(
-                subs, Policy.RO, cfg.geometry_dram, cfg.dram_capacity,
-                cls_subs)
-            alloc_s, dem_s, _ = self._size_level(
-                subs, Policy.WBWO, cfg.geometry_ssd, cfg.ssd_capacity,
-                cls_subs)
-            self.logs_dram.append(IntervalLog(dem_d, alloc_d))
-            self.logs_ssd.append(IntervalLog(dem_s, alloc_s))
-            # 2) resize both levels (shrinking flushes dirty blocks)
-            wd = np.asarray(capacity_to_ways(alloc_d, gd.num_sets,
-                                             gd.max_ways))
-            ws = np.asarray(capacity_to_ways(alloc_s, gs.num_sets,
-                                             gs.max_ways))
+        subs = win.subs
+        # 0) IO classification: one fused dispatch per window, the
+        # sequential-run carry threaded across windows per VM
+        cls_subs = None
+        if self.classifier is not None:
+            cls_subs, self._cls_end, self._cls_len = \
+                self.classifier.classify_subs(subs, self._cls_end,
+                                              self._cls_len)
+        # 1) POD sizing + PPC partitioning at both levels (§4.3)
+        alloc_d, dem_d, _ = self._size_level(
+            subs, Policy.RO, cfg.geometry_dram, cfg.dram_capacity,
+            cls_subs, level="dram")
+        alloc_s, dem_s, _ = self._size_level(
+            subs, Policy.WBWO, cfg.geometry_ssd, cfg.ssd_capacity,
+            cls_subs, level="ssd")
+        self.logs_dram.append(IntervalLog(dem_d, alloc_d))
+        self.logs_ssd.append(IntervalLog(dem_s, alloc_s))
+        with span("partition"):
+            wd = sync("ways", capacity_to_ways(alloc_d, gd.num_sets,
+                                               gd.max_ways))
+            ws = sync("ways", capacity_to_ways(alloc_s, gs.num_sets,
+                                               gs.max_ways))
             # dead-VM pad rows keep zero ways forever
             wd = np.pad(wd, (0, self._rows - self.num_vms))
             ws = np.pad(ws, (0, self._rows - self.num_vms))
-            if cfg.batched:
+        # 2) resize both levels (shrinking flushes dirty blocks)
+        if cfg.batched:
+            with span("resize"):
                 # both levels resized in ONE jitted dispatch (sharded:
                 # every device resizes its own row block)
                 if cfg.mesh is not None:
@@ -831,7 +839,7 @@ class EticaCache:
                         simulator.resize_levels(
                             self.dram, self.ssd, self.ways_dram, wd,
                             self.ways_ssd, ws)
-                flushed = np.asarray(flushed)
+                flushed = sync("resize", flushed)
                 for v in range(self.num_vms):
                     self.stats[v]["disk_writes"] = (
                         self.stats[v].get("disk_writes", 0.0)
@@ -839,48 +847,70 @@ class EticaCache:
                     self.stats[v]["evict_flushes"] = (
                         self.stats[v].get("evict_flushes", 0.0)
                         + int(flushed[v]))
-            else:
-                for v in range(self.num_vms):
-                    self.dram[v], _ = simulator.resize_ref(
-                        self.dram[v], int(self.ways_dram[v]), int(wd[v]))
-                    self.ssd[v], fl = simulator.resize_ref(
-                        self.ssd[v], int(self.ways_ssd[v]), int(ws[v]))
-                    self.stats[v]["disk_writes"] = (
-                        self.stats[v].get("disk_writes", 0.0) + fl)
-                    self.stats[v]["evict_flushes"] = (
-                        self.stats[v].get("evict_flushes", 0.0) + fl)
+        else:
             for v in range(self.num_vms):
-                alloc_hist[v].append(int(alloc_d[v] + alloc_s[v]))
-            self.ways_dram, self.ways_ssd = wd, ws
-            # class -> sub-partition way ranges for the new allocations
-            if self.classifier is not None:
-                self._lo_d, self._hi_d = self.classifier.way_bounds(wd)
-                self._lo_s, self._hi_s = self.classifier.way_bounds(ws)
-            # 3) datapath simulation in promo-interval chunks + maintenance
-            if cfg.batched:
-                # [V, chunk] blocks from the source (device-put one block
-                # ahead of the simulator when prefetch is on)
-                for k, (a, w, kth) in enumerate(win.blocks()):
-                    cmat = (None if cls_subs is None else
-                            _cls_chunk(cls_subs, k, cfg.promo_interval))
-                    self._run_chunk_batched(a, w, kth, cmat)
-                    if cfg.mode == "full":
-                        mth = (kth if cls_subs is None else _strip_bypass(
-                            kth, cls_subs, k, cfg.promo_interval, self._byp))
-                        self._maintain_all(mth)
+                self.dram[v], _ = simulator.resize_ref(
+                    self.dram[v], int(self.ways_dram[v]), int(wd[v]))
+                self.ssd[v], fl = simulator.resize_ref(
+                    self.ssd[v], int(self.ways_ssd[v]), int(ws[v]))
+                self.stats[v]["disk_writes"] = (
+                    self.stats[v].get("disk_writes", 0.0) + fl)
+                self.stats[v]["evict_flushes"] = (
+                    self.stats[v].get("evict_flushes", 0.0) + fl)
+        for v in range(self.num_vms):
+            alloc_hist[v].append(int(alloc_d[v] + alloc_s[v]))
+        self.ways_dram, self.ways_ssd = wd, ws
+        # class -> sub-partition way ranges for the new allocations
+        if self.classifier is not None:
+            self._lo_d, self._hi_d = self.classifier.way_bounds(wd)
+            self._lo_s, self._hi_s = self.classifier.way_bounds(ws)
+        # 3) datapath simulation in promo-interval chunks + maintenance
+        if cfg.batched:
+            # [V, chunk] blocks from the source (device-put one block
+            # ahead of the simulator when prefetch is on)
+            for k, (a, w, kth) in enumerate(win.blocks()):
+                cmat = (None if cls_subs is None else
+                        _cls_chunk(cls_subs, k, cfg.promo_interval))
+                self._run_chunk_batched(a, w, kth, cmat)
+                if cfg.mode == "full":
+                    mth = (kth if cls_subs is None else _strip_bypass(
+                        kth, cls_subs, k, cfg.promo_interval, self._byp))
+                    self._maintain_all(mth)
+                with span("telemetry"):
                     self._sample_interval()
-            else:
-                chunk_lists = win.chunk_lists()
-                for k in range(max(map(len, chunk_lists), default=0)):
-                    kth = [c[k] if k < len(c) else None for c in chunk_lists]
-                    self._run_chunk_sequential(kth, cls_subs, k)
-                    if cfg.mode == "full":
-                        mth = (kth if cls_subs is None else _strip_bypass(
-                            kth, cls_subs, k, cfg.promo_interval, self._byp))
-                        for v, chunk in enumerate(mth):
-                            if chunk is not None:
-                                self._maintain_seq(v, chunk)
-                    self._sample_interval()
+        else:
+            chunk_lists = win.chunk_lists()
+            for k in range(max(map(len, chunk_lists), default=0)):
+                kth = [c[k] if k < len(c) else None for c in chunk_lists]
+                self._run_chunk_sequential(kth, cls_subs, k)
+                if cfg.mode == "full":
+                    mth = (kth if cls_subs is None else _strip_bypass(
+                        kth, cls_subs, k, cfg.promo_interval, self._byp))
+                    for v, chunk in enumerate(mth):
+                        if chunk is not None:
+                            self._maintain_seq(v, chunk)
+                self._sample_interval()
+
+    def run(self, trace) -> list[VMResult]:
+        """Drive the controller over a whole trace.
+
+        ``trace`` may be an in-memory :class:`Trace`, an on-disk
+        :class:`repro.traces.store.TraceStore`, or a pre-built
+        :class:`repro.traces.stream.StreamingTraceSource` — all three
+        produce bit-identical results; the store/stream paths never hold
+        more than one resize window (plus the in-flight ``[V, chunk]``
+        blocks) in host memory."""
+        cfg = self.cfg
+        alloc_hist = [[] for _ in range(self.num_vms)]
+        source = _window_source(trace, self.num_vms, cfg.resize_interval,
+                                cfg.promo_interval, cfg.prefetch,
+                                cfg.prefetch_depth,
+                                self._rows - self.num_vms, self._sharding)
+        # each window's demux runs in next(), before its span, as `ingest`
+        for win in source.windows():
+            with span("window", index=win.index,
+                      requests=sum(map(len, win.subs))):
+                self._run_window(win, alloc_hist)
         return [VMResult(dict(self.stats[v]),
                          np.asarray(alloc_hist[v], np.int64))
                 for v in range(self.num_vms)]
@@ -989,7 +1019,6 @@ class PartitionedSingleLevelCache:
         if cfg.telemetry is not None:
             self.telemetry = cfg.telemetry
         else:
-            from repro.runtime.telemetry import TelemetryRecorder
             self.telemetry = TelemetryRecorder()
         self.classifier = cfg.classifier
         if self.classifier is not None:
@@ -1014,53 +1043,44 @@ class PartitionedSingleLevelCache:
             cls_hits=self.cls_hits if cls else None,
             cls_miss=self.cls_miss if cls else None)
 
-    def run(self, trace) -> list[VMResult]:
-        """Drive the chassis over a :class:`Trace`, an on-disk
-        :class:`repro.traces.store.TraceStore`, or a pre-built
-        :class:`repro.traces.stream.StreamingTraceSource` — bit-identical
-        results either way (the streamed paths hold one resize window at
-        a time)."""
+    def _run_window(self, win, alloc_hist: list[list[int]]) -> None:
+        """One resize window: size every VM by the metric, choose its
+        policy, partition and resize, then simulate chunk by chunk."""
         cfg = self.cfg
-        alloc_hist = [[] for _ in range(self.num_vms)]
-        source = _window_source(trace, self.num_vms, cfg.resize_interval,
-                                cfg.sim_chunk, cfg.prefetch,
-                                cfg.prefetch_depth,
-                                self._rows - self.num_vms, self._sharding)
-        for win in source.windows():
-            subs = win.subs
-            # IO classification: bypass-class requests never reach the
-            # cache, so they are cut from the sizing/policy sub-traces
-            cls_subs = None
-            subs_sz = subs
-            if self.classifier is not None:
-                cls_subs, self._cls_end, self._cls_len = \
-                    self.classifier.classify_subs(subs, self._cls_end,
-                                                  self._cls_len)
-                wts = self.classifier.weights
-                keep = [wts[c] > 0 for c in cls_subs]
-                subs_sz = [s if m.all() else s[m]
-                           for s, m in zip(subs, keep)]
+        subs = win.subs
+        # IO classification: bypass-class requests never reach the
+        # cache, so they are cut from the sizing/policy sub-traces
+        cls_subs = None
+        subs_sz = subs
+        if self.classifier is not None:
+            cls_subs, self._cls_end, self._cls_len = \
+                self.classifier.classify_subs(subs, self._cls_end,
+                                              self._cls_len)
+            wts = self.classifier.weights
+            keep = [wts[c] > 0 for c in cls_subs]
+            subs_sz = [s if m.all() else s[m]
+                       for s, m in zip(subs, keep)]
+        grid = _mrc_grid(cfg.geometry, cfg.mrc_points)
+        batched_metric = cfg.batched and hasattr(self.metric, "batch")
+        with self.telemetry.span("sizing") as sp:
             demands = np.zeros(self.num_vms, np.int64)
-            grid = _mrc_grid(cfg.geometry, cfg.mrc_points)
             curves = np.zeros((self.num_vms, grid.size))
-            batched_metric = cfg.batched and hasattr(self.metric, "batch")
             if batched_metric:
                 # all VMs' metrics in ONE vmapped reduction over the
                 # stacked reuse-distance histograms (empty rows stay 0);
                 # the dynamic policy choosers' read counts ride the same
                 # dispatch
-                with self.telemetry.span("sizing") as sp:
-                    pad = self._rows - self.num_vms
-                    dem, g_, cur, reads = self.metric.batch(
-                        [np.asarray(s.addr) for s in subs_sz]
-                        + [np.empty(0, np.int32)] * pad,
-                        [np.asarray(s.is_write) for s in subs_sz]
-                        + [np.empty(0, bool)] * pad,
-                        with_reads=True, mesh=cfg.mesh)
-                    dem, cur, reads = (dem[:self.num_vms],
-                                       cur[:self.num_vms],
-                                       reads[:self.num_vms])
-                    sp.ready((dem, cur))
+                pad = self._rows - self.num_vms
+                dem, g_, cur, reads = self.metric.batch(
+                    [np.asarray(s.addr) for s in subs_sz]
+                    + [np.empty(0, np.int32)] * pad,
+                    [np.asarray(s.is_write) for s in subs_sz]
+                    + [np.empty(0, bool)] * pad,
+                    with_reads=True, mesh=cfg.mesh)
+                dem, cur, reads = (dem[:self.num_vms],
+                                   cur[:self.num_vms],
+                                   reads[:self.num_vms])
+                sp.ready((dem, cur))
                 same_grid = np.array_equal(g_, grid)
                 for v, sub in enumerate(subs_sz):
                     if len(sub) == 0:
@@ -1076,6 +1096,7 @@ class PartitionedSingleLevelCache:
                     d, g_, c_ = metric_fn(sub)
                     demands[v] = min(d, cfg.geometry.capacity)
                     curves[v] = np.interp(grid, g_, c_)
+        with span("partition"):
             if batched_metric and isinstance(self.policy_fn, PolicyChooser):
                 policies = self.policy_fn.batch(reads,
                                                 [len(s) for s in subs_sz])
@@ -1092,36 +1113,10 @@ class PartitionedSingleLevelCache:
                                         cfg.geometry)
             self.logs.append(IntervalLog(demands, alloc,
                                          [p.value for p in policies]))
-            w_new = np.asarray(capacity_to_ways(
+            w_new = sync("ways", capacity_to_ways(
                 alloc, cfg.geometry.num_sets, cfg.geometry.max_ways))
             # dead-VM pad rows keep zero ways forever
             w_new = np.pad(w_new, (0, self._rows - self.num_vms))
-            if cfg.batched:
-                if cfg.mesh is not None:
-                    self.caches, flushed = simulator.resize_batch_sharded(
-                        self.caches, self.ways, w_new, cfg.mesh)
-                else:
-                    self.caches, flushed = resize_batch(
-                        self.caches, self.ways, w_new)
-                flushed = np.asarray(flushed)
-                for v in range(self.num_vms):
-                    self.stats[v]["disk_writes"] = (
-                        self.stats[v].get("disk_writes", 0.0)
-                        + int(flushed[v]))
-                    self.stats[v]["evict_flushes"] = (
-                        self.stats[v].get("evict_flushes", 0.0)
-                        + int(flushed[v]))
-            else:
-                for v in range(self.num_vms):
-                    self.caches[v], fl = simulator.resize_ref(
-                        self.caches[v], int(self.ways[v]), int(w_new[v]))
-                    self.stats[v]["disk_writes"] = (
-                        self.stats[v].get("disk_writes", 0.0) + fl)
-                    self.stats[v]["evict_flushes"] = (
-                        self.stats[v].get("evict_flushes", 0.0) + fl)
-            for v in range(self.num_vms):
-                alloc_hist[v].append(int(alloc[v]))
-            self.ways = w_new
             # pad rows get the WB default — dead VMs (0 ways, addr -1
             # blocks) never touch their cache whatever the policy says
             flags = policy_flags(
@@ -1131,69 +1126,116 @@ class PartitionedSingleLevelCache:
                 flags_vc = _class_policy_flags(
                     self.classifier.vm_policies(policies))
                 lo, hi = self.classifier.way_bounds(w_new)
-            if cfg.batched:
-                # [V, chunk] blocks from the source (device-put one block
-                # ahead of the simulator when prefetch is on)
-                for k, (a, wr, kth) in enumerate(win.blocks()):
-                    with self.telemetry.span("datapath") as sp:
-                        if cls_subs is None and cfg.mesh is not None:
-                            self.caches, st, t_end = \
-                                simulator.simulate_single_level_sharded(
-                                    a, wr, self.caches, self.ways, flags,
-                                    cfg.mesh, t0=self.t)
-                        elif cls_subs is None:
-                            self.caches, st, t_end = \
-                                simulator.simulate_single_level_batch(
-                                    a, wr, self.caches, self.ways, flags,
-                                    t0=self.t)
-                        else:
-                            cmat = _cls_chunk(cls_subs, k, cfg.sim_chunk)
-                            self.caches, st, t_end, ch, cm = simulator.\
-                                simulate_single_level_classified_batch(
-                                    a, wr, cmat, self.caches, self.ways,
-                                    flags_vc, lo, hi, self._byp, t0=self.t)
-                            ch, cm = jax.device_get((ch, cm))
-                            self.cls_hits += np.asarray(ch, np.int64)
-                            self.cls_miss += np.asarray(cm, np.int64)
-                        sp.ready(st)
-                    self.t = np.asarray(t_end)
-                    st = jax.device_get(st)
+        if cfg.batched:
+            with span("resize"):
+                if cfg.mesh is not None:
+                    self.caches, flushed = simulator.resize_batch_sharded(
+                        self.caches, self.ways, w_new, cfg.mesh)
+                else:
+                    self.caches, flushed = resize_batch(
+                        self.caches, self.ways, w_new)
+                flushed = sync("resize", flushed)
+                for v in range(self.num_vms):
+                    self.stats[v]["disk_writes"] = (
+                        self.stats[v].get("disk_writes", 0.0)
+                        + int(flushed[v]))
+                    self.stats[v]["evict_flushes"] = (
+                        self.stats[v].get("evict_flushes", 0.0)
+                        + int(flushed[v]))
+        else:
+            for v in range(self.num_vms):
+                self.caches[v], fl = simulator.resize_ref(
+                    self.caches[v], int(self.ways[v]), int(w_new[v]))
+                self.stats[v]["disk_writes"] = (
+                    self.stats[v].get("disk_writes", 0.0) + fl)
+                self.stats[v]["evict_flushes"] = (
+                    self.stats[v].get("evict_flushes", 0.0) + fl)
+        for v in range(self.num_vms):
+            alloc_hist[v].append(int(alloc[v]))
+        self.ways = w_new
+        if cfg.batched:
+            # [V, chunk] blocks from the source (device-put one block
+            # ahead of the simulator when prefetch is on)
+            for k, (a, wr, kth) in enumerate(win.blocks()):
+                with self.telemetry.span("datapath") as sp:
+                    if cls_subs is None and cfg.mesh is not None:
+                        self.caches, st, t_end = \
+                            simulator.simulate_single_level_sharded(
+                                a, wr, self.caches, self.ways, flags,
+                                cfg.mesh, t0=self.t)
+                    elif cls_subs is None:
+                        self.caches, st, t_end = \
+                            simulator.simulate_single_level_batch(
+                                a, wr, self.caches, self.ways, flags,
+                                t0=self.t)
+                    else:
+                        cmat = _cls_chunk(cls_subs, k, cfg.sim_chunk)
+                        self.caches, st, t_end, ch, cm = simulator.\
+                            simulate_single_level_classified_batch(
+                                a, wr, cmat, self.caches, self.ways,
+                                flags_vc, lo, hi, self._byp, t0=self.t)
+                        ch, cm = sync("classes", (ch, cm))
+                        self.cls_hits += np.asarray(ch, np.int64)
+                        self.cls_miss += np.asarray(cm, np.int64)
+                    sp.ready(st)
+                with span("stats"):
+                    self.t = sync("clock", t_end)
+                    st = sync("stats", st)
                     for v, chunk in enumerate(kth):
                         if chunk is not None:
                             _acc(self.stats[v], Stats(*[f[v] for f in st]))
+                with span("telemetry"):
                     self._sample_interval()
-            else:
-                chunk_lists = win.chunk_lists()
-                for k in range(max(map(len, chunk_lists), default=0)):
-                    kth = [c[k] if k < len(c) else None for c in chunk_lists]
-                    for v, chunk in enumerate(kth):
-                        if chunk is None:
-                            continue
-                        a, wr = _pad(np.asarray(chunk.addr, np.int32),
-                                     np.asarray(chunk.is_write),
-                                     cfg.sim_chunk)
-                        if cls_subs is None:
-                            self.caches[v], st, t_end = \
-                                simulator.simulate_single_level(
-                                    a, wr, self.caches[v], int(self.ways[v]),
-                                    policies[v], t0=int(self.t[v]))
-                        else:
-                            seg = cls_subs[v][k * cfg.sim_chunk:
-                                              (k + 1) * cfg.sim_chunk]
-                            cpad = np.zeros(cfg.sim_chunk, np.int32)
-                            cpad[:len(seg)] = seg
-                            fv = simulator.PolicyFlags(
-                                *[np.asarray(f[v]) for f in flags_vc])
-                            self.caches[v], st, t_end, ch, cm = \
-                                simulator.simulate_single_level_classified(
-                                    a, wr, cpad, self.caches[v],
-                                    int(self.ways[v]), fv, lo[v], hi[v],
-                                    self._byp, t0=int(self.t[v]))
-                            self.cls_hits[v] += np.asarray(ch, np.int64)
-                            self.cls_miss[v] += np.asarray(cm, np.int64)
-                        self.t[v] = int(t_end)
-                        _acc(self.stats[v], st)
-                    self._sample_interval()
+        else:
+            chunk_lists = win.chunk_lists()
+            for k in range(max(map(len, chunk_lists), default=0)):
+                kth = [c[k] if k < len(c) else None for c in chunk_lists]
+                for v, chunk in enumerate(kth):
+                    if chunk is None:
+                        continue
+                    a, wr = _pad(np.asarray(chunk.addr, np.int32),
+                                 np.asarray(chunk.is_write),
+                                 cfg.sim_chunk)
+                    if cls_subs is None:
+                        self.caches[v], st, t_end = \
+                            simulator.simulate_single_level(
+                                a, wr, self.caches[v], int(self.ways[v]),
+                                policies[v], t0=int(self.t[v]))
+                    else:
+                        seg = cls_subs[v][k * cfg.sim_chunk:
+                                          (k + 1) * cfg.sim_chunk]
+                        cpad = np.zeros(cfg.sim_chunk, np.int32)
+                        cpad[:len(seg)] = seg
+                        fv = simulator.PolicyFlags(
+                            *[np.asarray(f[v]) for f in flags_vc])
+                        self.caches[v], st, t_end, ch, cm = \
+                            simulator.simulate_single_level_classified(
+                                a, wr, cpad, self.caches[v],
+                                int(self.ways[v]), fv, lo[v], hi[v],
+                                self._byp, t0=int(self.t[v]))
+                        self.cls_hits[v] += np.asarray(ch, np.int64)
+                        self.cls_miss[v] += np.asarray(cm, np.int64)
+                    self.t[v] = int(t_end)
+                    _acc(self.stats[v], st)
+                self._sample_interval()
+
+    def run(self, trace) -> list[VMResult]:
+        """Drive the chassis over a :class:`Trace`, an on-disk
+        :class:`repro.traces.store.TraceStore`, or a pre-built
+        :class:`repro.traces.stream.StreamingTraceSource` — bit-identical
+        results either way (the streamed paths hold one resize window at
+        a time)."""
+        cfg = self.cfg
+        alloc_hist = [[] for _ in range(self.num_vms)]
+        source = _window_source(trace, self.num_vms, cfg.resize_interval,
+                                cfg.sim_chunk, cfg.prefetch,
+                                cfg.prefetch_depth,
+                                self._rows - self.num_vms, self._sharding)
+        # each window's demux runs in next(), before its span, as `ingest`
+        for win in source.windows():
+            with span("window", index=win.index,
+                      requests=sum(map(len, win.subs))):
+                self._run_window(win, alloc_hist)
         return [VMResult(dict(self.stats[v]),
                          np.asarray(alloc_hist[v], np.int64))
                 for v in range(self.num_vms)]

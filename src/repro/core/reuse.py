@@ -60,6 +60,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime.telemetry import sync
+
 from .policies import Policy
 
 # Sentinel distance for cold / not-served accesses. A numpy scalar, not a
@@ -311,8 +313,9 @@ def _decompose_sharded(mesh, amat, wmat, policy, sizing_reads_only, chunk):
         parts.append(_decompose_vmapped(a, w, policy=policy,
                                         sizing_reads_only=sizing_reads_only,
                                         chunk=chunk))
+    parts = sync("decompose", parts)
     return DistResult(*[
-        np.concatenate([np.asarray(getattr(p, f)) for p in parts], axis=0)
+        np.concatenate([getattr(p, f) for p in parts], axis=0)
         for f in ("dist", "served", "touch")])
 
 
@@ -326,9 +329,9 @@ def _sizing_sharded(mesh, amat, wmat, nvec, grid, kind, chunk):
         g = jax.device_put(jnp.asarray(grid), dev)
         parts.append(_sizing_reduce_vmapped(a, w, n, g,
                                             kind=kind, chunk=chunk))
-    return tuple(
-        np.concatenate([np.asarray(p[i]) for p in parts], axis=0)
-        for i in range(3))
+    parts = sync("sizing", parts)
+    return tuple(np.concatenate([p[i] for p in parts], axis=0)
+                 for i in range(3))
 
 
 def _require_divisible(num_rows: int, mesh) -> None:
@@ -365,13 +368,12 @@ def _distances_batch(addrs, writes, policy: Policy, sizing_reads_only: bool,
         idx = rows
     else:
         amat, wmat = _pad_rows(addrs, writes, live, lens)
-        r = _decompose_vmapped(amat, wmat, policy=policy,
-                               sizing_reads_only=sizing_reads_only,
-                               chunk=chunk)
+        r = sync("decompose", _decompose_vmapped(
+            amat, wmat, policy=policy, sizing_reads_only=sizing_reads_only,
+            chunk=chunk))
         idx = live
     out: list[DistResult | None] = [None] * len(lens)
-    dist, served, touch = (np.asarray(r.dist), np.asarray(r.served),
-                           np.asarray(r.touch))
+    dist, served, touch = r.dist, r.served, r.touch
     for i, v in enumerate(idx):
         if lens[v] > 0:
             out[v] = DistResult(dist=dist[i, : lens[v]],
@@ -616,8 +618,8 @@ def sizing_metrics_batch(addrs, writes, kind: str, grid,
         return demands, hits, reads
     amat, wmat = _pad_rows(addrs, writes, live, lens)
     nvec = np.array([lens[v] for v in live], np.int32)
-    d, h, r = _sizing_reduce_vmapped(amat, wmat, nvec, jnp.asarray(grid),
-                                     kind=kind, chunk=chunk)
+    d, h, r = sync("sizing", _sizing_reduce_vmapped(
+        amat, wmat, nvec, jnp.asarray(grid), kind=kind, chunk=chunk))
     demands[live] = np.asarray(d, np.int64)
     hits[live] = np.asarray(h, np.int64)
     reads[live] = np.asarray(r, np.int64)

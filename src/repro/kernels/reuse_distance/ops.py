@@ -38,6 +38,7 @@ import numpy as np
 from repro.core.policies import Policy
 from repro.core import reuse as core_reuse
 from repro.kernels import resolve_interpret
+from repro.runtime.telemetry import sync
 from .kernel import count_between
 
 
@@ -154,9 +155,9 @@ def _sizing_sharded(mesh, amat, wmat, nvec, grid, kind, interpret, ti, tj):
         parts.append(_sizing_reduce_vmapped(a, w, n, g, kind=kind,
                                             interpret=interpret,
                                             ti=ti, tj=tj))
-    return tuple(
-        np.concatenate([np.asarray(p[i]) for p in parts], axis=0)
-        for i in range(3))
+    parts = sync("sizing", parts)
+    return tuple(np.concatenate([p[i] for p in parts], axis=0)
+                 for i in range(3))
 
 
 def sizing_metrics_batch(addrs, writes, kind: str, grid, *,
@@ -204,9 +205,9 @@ def sizing_metrics_batch(addrs, writes, kind: str, grid, *,
         return demands, hits, reads
     amat, wmat = core_reuse._pad_rows(addrs, writes, live, lens)
     nvec = np.array([lens[v] for v in live], np.int32)
-    d, h, r = _sizing_reduce_vmapped(amat, wmat, nvec, jnp.asarray(grid),
-                                     kind=kind, interpret=interpret,
-                                     ti=ti, tj=tj)
+    d, h, r = sync("sizing", _sizing_reduce_vmapped(
+        amat, wmat, nvec, jnp.asarray(grid), kind=kind,
+        interpret=interpret, ti=ti, tj=tj))
     demands[live] = np.asarray(d, np.int64)
     hits[live] = np.asarray(h, np.int64)
     reads[live] = np.asarray(r, np.int64)

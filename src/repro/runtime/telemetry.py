@@ -2,24 +2,29 @@
 
 ETICA's claims are *trajectories over maintenance intervals* (performance
 and endurance per §6), so the observability layer records one structured
-sample per interval rather than a single end-of-run aggregate. Three
-pieces, all dependency-free (numpy + stdlib; jax is imported lazily and
-only by the opt-in span timers):
+sample per interval rather than a single end-of-run aggregate. Four
+pieces, on numpy, the stdlib and (for the spans) ``jax``:
 
 * :class:`Journal` — a bounded columnar ring of per-interval samples
   (O(window) host memory regardless of run length) with an optional
   JSONL *spill*: every appended row is also written as one JSON line, so
   the full trajectory survives on disk while memory stays bounded.
   :func:`load_journal` reads a spill file back into stacked columns.
+* :func:`span` / :func:`sync` — host spans on the profiler's clock. A
+  span is a ``jax.profiler.TraceAnnotation`` and nothing else (no sync,
+  no clock read, no state): it records only while a profiler session is
+  active, beside the device's own trace. :func:`sync` is the one
+  device→host read of the controllers' batched paths, each one a
+  ``sync:<site>`` span, so the trace counts the host round trips.
 * :class:`TelemetryRecorder` — the object the controllers thread through
   their interval loops. ``sample_cache`` / ``sample_serving`` turn the
   host-side stats the controller *already fetched* into per-interval
   deltas — the recorder performs no device→host transfers of its own, so
   ``telemetry`` on vs off is bit-identical and sync-count-identical.
-  Opt-in extras: ``span_timing`` wall-clock histograms around the fused
-  dispatches (``span()`` calls ``jax.block_until_ready`` at close, so it
-  IS documented as adding syncs), and a ``jax.profiler.trace`` hook
-  (``profile()``).
+  Its ``span()`` opens the same annotation as :func:`span`; opt-in
+  ``span_timing`` adds wall-clock histograms around the fused dispatches
+  (``span()`` then calls ``jax.block_until_ready`` at close, so it IS
+  documented as adding syncs).
 * :func:`overload_flags` — LBICA-style per-interval overload *detection*
   (PAPERS.md): a VM/tenant is flagged when its windowed hit ratio
   collapses below ``drop × best-recent-baseline`` or its dirty/used
@@ -35,17 +40,17 @@ from __future__ import annotations
 
 import bisect
 import collections
-import contextlib
 import dataclasses
 import json
 import time
 
+import jax
 import numpy as np
 
 __all__ = [
     "DISPATCH_BUCKETS", "Journal", "OverloadConfig", "SpanStats",
-    "TelemetryRecorder", "load_journal", "overload_flags",
-    "summarize_journal",
+    "TelemetryRecorder", "load_journal", "overload_flags", "span",
+    "summarize_journal", "sync",
 ]
 
 # Golden-pinned histogram bucket bounds (seconds) for the dispatch span
@@ -187,8 +192,26 @@ def load_journal(path) -> dict[str, np.ndarray]:
 
 
 # ---------------------------------------------------------------------------
-# dispatch-span histograms (opt-in: adds block_until_ready syncs)
+# host spans on the profiler's clock; dispatch-span histograms (opt-in:
+# adds block_until_ready syncs)
 # ---------------------------------------------------------------------------
+
+def span(name: str, **meta):
+    """A host span named ``name`` on the profiler's clock, ``meta`` as its
+    arguments: a ``jax.profiler.TraceAnnotation`` and nothing else — no
+    sync, no clock read, no state. It records only while a profiler
+    session is active; with none it costs about a microsecond."""
+    return jax.profiler.TraceAnnotation(name, **meta)
+
+
+def sync(site: str, x):
+    """Read ``x`` (an array or a pytree of them, read at once) back to the
+    host: ``jax.device_get`` under a ``sync:<site>`` span. Every
+    device→host read of the controllers' batched paths goes through here,
+    so the span is the count of host round trips."""
+    with jax.profiler.TraceAnnotation("sync:" + site):
+        return jax.device_get(x)
+
 
 class SpanStats:
     """One wall-clock histogram: fixed bucket edges, per-bucket counts
@@ -209,15 +232,18 @@ class SpanStats:
 
 
 class _Span:
-    """Times a block and blocks on the value handed to :meth:`ready` at
-    close — the explicit sync that makes the measurement mean "dispatch
-    complete", and the reason span timing is opt-in."""
+    """A recorder span: the annotation of :func:`span`, and with a
+    recorder (``span_timing`` on) a timer that blocks on the value handed
+    to :meth:`ready` at close — the explicit sync that makes the
+    measurement mean "dispatch complete", and the reason span timing is
+    opt-in."""
 
-    __slots__ = ("_rec", "_name", "_t0", "_val")
+    __slots__ = ("_rec", "_name", "_ann", "_t0", "_val")
 
-    def __init__(self, rec, name):
+    def __init__(self, rec, name, meta):
         self._rec = rec
         self._name = name
+        self._ann = span(name, **meta)
         self._val = None
 
     def ready(self, value) -> None:
@@ -225,16 +251,19 @@ class _Span:
         self._val = value
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        if exc[0] is None:
-            if self._val is not None:
-                import jax
-                jax.block_until_ready(self._val)
-            self._rec._observe_span(self._name,
-                                    time.perf_counter() - self._t0)
+        try:
+            if self._rec is not None and exc[0] is None:
+                if self._val is not None:
+                    jax.block_until_ready(self._val)
+                self._rec._observe_span(self._name,
+                                        time.perf_counter() - self._t0)
+        finally:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -313,43 +342,40 @@ class TelemetryRecorder:
     Guarantees: ``sample_*`` only reads host-side values the controller
     already fetched (zero added device→host syncs) and never touches
     cache state (telemetry on vs off is bit-identical — asserted in
-    ``tests/test_telemetry.py``). ``span_timing`` and ``profile_dir``
-    are the opt-in exceptions that DO add synchronization, and say so.
+    ``tests/test_telemetry.py``). ``span_timing`` is the opt-in
+    exception that DOES add synchronization, and says so.
     """
 
     def __init__(self, window: int = 512, spill=None,
                  span_timing: bool = False,
-                 overload: OverloadConfig | None = None,
-                 profile_dir=None):
+                 overload: OverloadConfig | None = None):
         self.journal = Journal(window=window, spill=spill)
         self.span_timing = bool(span_timing)
         self.spans: dict[str, SpanStats] = {}
         self.overload = overload if overload is not None else OverloadConfig()
-        self.profile_dir = profile_dir
         self._prev: dict[str, np.ndarray] = {}
         self._ov_hits = collections.deque(maxlen=self.overload.window)
         self._ov_reqs = collections.deque(maxlen=self.overload.window)
 
     # -- spans ------------------------------------------------------------
-    def span(self, name: str):
-        """Context manager timing one dispatch; hand the dispatch output
-        to ``.ready(out)`` so close can ``block_until_ready`` it. A
-        no-op (and sync-free) unless ``span_timing`` is on."""
-        return _Span(self, name) if self.span_timing else _NULL_SPAN
+    def span(self, name: str, **meta):
+        """The host span :func:`span` opens, as a context manager; hand
+        the dispatch output to ``.ready(out)``. With ``span_timing`` on it
+        also times the block into ``spans[name]``, and close waits on that
+        output with ``block_until_ready``; off, it adds no sync, and with
+        no profiler session to record the annotation it is the shared
+        no-op."""
+        if self.span_timing:
+            return _Span(self, name, meta)
+        if jax.profiler.TraceAnnotation.is_enabled():
+            return _Span(None, name, meta)
+        return _NULL_SPAN
 
     def _observe_span(self, name: str, seconds: float) -> None:
         s = self.spans.get(name)
         if s is None:
             s = self.spans[name] = SpanStats()
         s.observe(seconds)
-
-    def profile(self):
-        """``jax.profiler.trace`` over a region when ``profile_dir`` is
-        set; a null context otherwise."""
-        if self.profile_dir is None:
-            return contextlib.nullcontext()
-        import jax
-        return jax.profiler.trace(str(self.profile_dir))
 
     # -- interval samples -------------------------------------------------
     def _deltas(self, cur: dict[str, np.ndarray]) -> dict[str, np.ndarray]:

@@ -56,6 +56,7 @@ import jax
 import numpy as np
 
 from repro.core.trace import Trace, pad_batch, split_by_vm
+from repro.runtime.telemetry import span
 
 from .store import TraceStore
 
@@ -80,14 +81,17 @@ class StreamWindow:
         maintenance path consumes (real VMs only — never padded). With
         ``prefetch_depth > 0`` the arrays arrive as device buffers, put up
         to that many blocks ahead of consumption; with ``sharding`` each
-        transfer lands directly in the per-device row-block layout."""
-        lists = self.chunk_lists()
+        transfer lands directly in the per-device row-block layout.
+        Building and putting each block is an ``ingest`` span."""
+        with span("ingest"):
+            lists = self.chunk_lists()
         n_chunks = max(map(len, lists), default=0)
         pad = [None] * self.pad_vms
 
         def host_block(k: int):
-            kth = [c[k] if k < len(c) else None for c in lists]
-            a, w = pad_batch(kth + pad, self.chunk)
+            with span("ingest"):
+                kth = [c[k] if k < len(c) else None for c in lists]
+                a, w = pad_batch(kth + pad, self.chunk)
             return a, w, kth
 
         if self.prefetch_depth <= 0:
@@ -95,9 +99,10 @@ class StreamWindow:
             return
 
         def put(a, w):
-            if self.sharding is None:
-                return jax.device_put((a, w))
-            return jax.device_put((a, w), self.sharding)
+            with span("ingest"):
+                if self.sharding is None:
+                    return jax.device_put((a, w))
+                return jax.device_put((a, w), self.sharding)
 
         pending: deque = deque()
         for k in range(min(self.prefetch_depth, n_chunks)):
@@ -188,7 +193,9 @@ class StreamingTraceSource:
     # -- in-memory ---------------------------------------------------------
     def _windows_from_trace(self, trace: Trace) -> Iterator[StreamWindow]:
         for i, window in enumerate(trace.intervals(self.window)):
-            yield self._window(i, split_by_vm(window, self.num_vms))
+            with span("ingest"):
+                subs = split_by_vm(window, self.num_vms)
+            yield self._window(i, subs)
 
     # -- on-disk, vm channel ----------------------------------------------
     def _windows_from_store(self, store: TraceStore) -> Iterator[StreamWindow]:
@@ -199,29 +206,31 @@ class StreamingTraceSource:
         empty = (np.empty(0, np.int32), np.empty(0, bool),
                  np.empty(0, np.int32) if sized else None)
         for i, ws in enumerate(range(0, total, self.window)):
-            we = min(ws + self.window, total)
-            while loaded < we:            # one stable sort per shard, once
-                sh = store.shard(shard_idx)
-                active.append(_DemuxedShard.demux(sh, loaded, self.num_vms))
-                loaded += len(sh)
-                shard_idx += 1
-            while active and active[0].base + active[0].length <= ws:
-                active.popleft()          # shard fully behind this window
-            subs = []
-            for v in range(self.num_vms):
-                parts = [d.vm_part(v, ws, we) for d in active]
-                parts = [p for p in parts if p[0].size]
-                if not parts:
-                    subs.append(Trace(empty[0], empty[1], size=empty[2]))
-                elif len(parts) == 1:
-                    subs.append(Trace(parts[0][0], parts[0][1],
-                                      size=parts[0][2]))
-                else:
-                    subs.append(Trace(
-                        np.concatenate([p[0] for p in parts]),
-                        np.concatenate([p[1] for p in parts]),
-                        size=(np.concatenate([p[2] for p in parts])
-                              if sized else None)))
+            with span("ingest"):
+                we = min(ws + self.window, total)
+                while loaded < we:        # one stable sort per shard, once
+                    sh = store.shard(shard_idx)
+                    active.append(
+                        _DemuxedShard.demux(sh, loaded, self.num_vms))
+                    loaded += len(sh)
+                    shard_idx += 1
+                while active and active[0].base + active[0].length <= ws:
+                    active.popleft()      # shard fully behind this window
+                subs = []
+                for v in range(self.num_vms):
+                    parts = [d.vm_part(v, ws, we) for d in active]
+                    parts = [p for p in parts if p[0].size]
+                    if not parts:
+                        subs.append(Trace(empty[0], empty[1], size=empty[2]))
+                    elif len(parts) == 1:
+                        subs.append(Trace(parts[0][0], parts[0][1],
+                                          size=parts[0][2]))
+                    else:
+                        subs.append(Trace(
+                            np.concatenate([p[0] for p in parts]),
+                            np.concatenate([p[1] for p in parts]),
+                            size=(np.concatenate([p[2] for p in parts])
+                                  if sized else None)))
             yield self._window(i, subs)
 
     # -- on-disk, no vm channel (single-stream convention) -----------------

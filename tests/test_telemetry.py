@@ -20,6 +20,8 @@ The load-bearing guarantees, each pinned here:
 * **Live scrape** — the stdlib endpoint serves parseable exposition
   with the telemetry families present.
 """
+import collections
+import re
 import urllib.error
 import urllib.request
 
@@ -237,6 +239,141 @@ def test_span_timing_opt_in():
         with rec.span("demo"):
             raise RuntimeError("boom")
     assert rec.spans["demo"].n == 1
+
+
+# reading an event's arguments makes jaxlib build its type, which warns
+EVENT_STATS = pytest.mark.filterwarnings(
+    "ignore:builtin type event_stats:DeprecationWarning")
+
+
+def _session():
+    from jaxlib import _profiler
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return _profiler.ProfilerSession(opts)
+
+
+def _host_events(profile, marker):
+    """(name, start_ns, end_ns, args) of every event on the host line
+    that carries the ``marker`` annotation, outer spans first."""
+    for plane in profile.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            ev = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                   dict(e.stats)) for e in line.events]
+            if any(n == marker for n, *_ in ev):
+                return sorted(ev, key=lambda x: (x[1], -x[2]))
+    raise AssertionError(f"no host line carries {marker!r}")
+
+
+@EVENT_STATS
+def test_recorder_span_is_the_profiler_annotation():
+    session = _session()
+    with T.span("outer", k=1):
+        # timing off: in a session the same annotation as span(), no sync
+        with TelemetryRecorder().span("quiet", level="dram") as sp:
+            sp.ready(jnp.arange(4))
+        rec = TelemetryRecorder(span_timing=True)
+        with rec.span("timed") as sp:
+            sp.ready(jnp.arange(4) + 1)
+        got = T.sync("site", jnp.arange(3))
+    ev = _host_events(session.stop_and_get_profile_data(), "outer")
+    names = [n for n, *_ in ev if n in ("outer", "quiet", "timed",
+                                         "sync:site")]
+    assert names == ["outer", "quiet", "timed", "sync:site"]
+    assert [a for n, *_, a in ev if n == "quiet"] == [{"level": "dram"}]
+    assert rec.spans["timed"].n == 1
+    assert np.array_equal(got, np.arange(3))
+
+
+def _phase_sequence(spans, lo, hi):
+    """Names of the outermost spans inside ``[lo, hi]``, in order."""
+    out, end = [], lo
+    for n, s, e, _ in spans:
+        if s >= end and e <= hi:
+            out.append(n)
+            end = e
+    return out
+
+
+@EVENT_STATS
+@pytest.mark.parametrize("name", ["paper12.msr", "eci12.msr"])
+def test_batched_loops_span_every_phase_and_sync(monkeypatch, name):
+    """The tiny benchmark cells under a profiler session: one ``window``
+    span per resize window, preceded by its demux under ``ingest`` and
+    holding the phases in loop order; one ``sync:`` span per
+    ``jax.device_get``, each inside a phase, as many per window as the
+    code's sync sites predict; Stats bit-identical to an untraced run."""
+    from bench.lib import traffic
+    from bench.tests.tiny import tiny_cell
+    cell = tiny_cell(name)
+    cfg, ctl = cell.config, cell.controller
+    s = traffic.stream(cell.traffic, 2**31 + 7)
+    r, windows = cfg["resize_interval"], 3
+    args = (s.addr[:windows * r], s.is_write[:windows * r],
+            s.vm[:windows * r])
+    plain = ctl.build(cfg, cfg["num_vms"])
+    ctl.run(plain, *args)
+
+    counter = _CountingGet(jax.device_get)
+    monkeypatch.setattr(jax, "device_get", counter)
+    traced = ctl.build(cfg, cfg["num_vms"])
+    session = _session()
+    with jax.profiler.TraceAnnotation("test.run"):
+        ctl.run(traced, *args)
+    profile = session.stop_and_get_profile_data()
+    monkeypatch.undo()
+    assert traced.stats == plain.stats
+    a, b = ctl.state(traced), ctl.state(plain)
+    for k in a:
+        if isinstance(a[k], list):
+            assert all(np.array_equal(x, y) for x, y in zip(a[k], b[k])), k
+        else:
+            assert np.array_equal(a[k], b[k]), k
+
+    ev = _host_events(profile, "test.run")
+    phases = {"ingest", "sizing", "partition", "resize", "datapath",
+              "stats", "maintenance", "telemetry"}
+    spans = [x for x in ev if x[0] in phases | {"window"}
+             or x[0].startswith("sync:")]
+    run = next(x for x in ev if x[0] == "test.run")
+    assert _phase_sequence(spans, run[1], run[2]) == ["ingest",
+                                                     "window"] * windows
+    wins = [x for x in spans if x[0] == "window"]
+    assert [w[3]["index"] for w in wins] == list(range(windows))
+    assert all(w[3]["requests"] == r for w in wins)
+
+    syncs = [x for x in spans if x[0].startswith("sync:")]
+    assert len(syncs) == counter.n
+    tops = [x for x in spans if x[0] in phases]
+    for n, s0, e0, _ in syncs:
+        assert any(p0 <= s0 and e0 <= p1 for _, p0, p1, _ in tops), n
+
+    etica = cfg["controller"] == "etica"
+    chunk = cfg["promo_interval"] if etica else cfg["sim_chunk"]
+    interval = ("datapath stats maintenance telemetry" if etica
+                else "datapath stats telemetry")
+    head = ("sizing partition sizing partition partition resize" if etica
+            else "sizing partition resize")
+    loop = re.compile(rf"{head}( (ingest )*{interval})+")
+    inner = [x for x in spans if x[0] != "window"]
+    for i, (_, w0, w1, _) in enumerate(wins):
+        seq = _phase_sequence(inner, w0, w1)
+        assert loop.fullmatch(" ".join(seq)), seq
+        counts = np.bincount(args[2][i * r:(i + 1) * r],
+                             minlength=cfg["num_vms"])
+        chunks = -(-int(counts.max()) // chunk)
+        live = int((counts > 0).sum())
+        sites = collections.Counter(n for n, s0, e0, _ in syncs
+                                    if w0 <= s0 and e0 <= w1)
+        want = ({"sync:decompose": 2, "sync:demand": 2 * live,
+                 "sync:ways": 2, "sync:resize": 1, "sync:clock": chunks,
+                 "sync:stats": chunks, "sync:maintenance": chunks}
+                if etica else
+                {"sync:sizing": 1, "sync:ways": 1, "sync:resize": 1,
+                 "sync:clock": chunks, "sync:stats": chunks})
+        assert sites == want, (i, sites)
 
 
 HIST_GOLDEN = """\
