@@ -49,6 +49,11 @@ from .simulator import (CacheState, Stats, capacity_to_ways, make_cache,
 from .trace import Trace
 
 
+# fused-maintenance executables already compiled at every way bucket, by
+# the shapes and options of the dispatch (see EticaCache._warm_ways_buckets)
+_WARMED_WAYS_BUCKETS: set = set()
+
+
 def _window_source(trace, num_vms: int, window: int, chunk: int,
                    prefetch: bool, prefetch_depth: int = 2,
                    pad_vms: int = 0, sharding=None):
@@ -553,7 +558,12 @@ class EticaCache:
         live = [v for v, n in enumerate(lens) if n > 0]
         if not live:
             return
-        with self.telemetry.span("maintenance") as sp:
+        # the dispatch works on the SSD level's leading `wb` ways, enough
+        # for every VM's active ways (the rest hold no block)
+        wb = maint_ops.ways_bucket_of(self.ways_ssd,
+                                      cfg.geometry_ssd.max_ways)
+        self.telemetry.ways_buckets[wb] += 1
+        with self.telemetry.span("maintenance", ways_bucket=wb) as sp:
             # batched TRD decomposition (same bucketing as
             # trd_distances_batch) — results stay on device and feed the
             # fused dispatch directly. ALL VMs ride as rows (idle ones
@@ -570,12 +580,15 @@ class EticaCache:
                 r = reuse._decompose_vmapped(amat, wmat, policy=Policy.WB,
                                              sizing_reads_only=False,
                                              chunk=256)
+            args = (self.ssd, self.pop_table, r.dist, r.served, amat)
+            kw = dict(evict_frac=cfg.evict_frac,
+                      decay=cfg.popularity_decay,
+                      clean_quota=cfg.clean_quota, mesh=cfg.mesh)
+            self._warm_ways_buckets(args, kw)
             (self.ssd, self.pop_table, flushed, promoted, eqlen, pqlen,
              pdrops, cleaned, dirty_left) = maint_ops.maintenance_interval(
-                    self.ssd, self.pop_table, r.dist, r.served, amat,
-                    np.asarray(lens, np.int32), self.ways_ssd, self.t,
-                    evict_frac=cfg.evict_frac, decay=cfg.popularity_decay,
-                    clean_quota=cfg.clean_quota, mesh=cfg.mesh)
+                    *args, np.asarray(lens, np.int32), self.ways_ssd, self.t,
+                    ways_bucket=wb, **kw)
             sp.ready((self.ssd, self.pop_table, flushed))
             # ONE host transfer for all per-VM counters — the cleaner's
             # two vectors ride the sync the interval already paid for
@@ -630,6 +643,33 @@ class EticaCache:
                 self._m_cleaned += np.asarray(cleaned, np.int64)
                 self._m_dirty = np.asarray(dirty_left, np.int64)
                 self._m_clean_ran = True
+
+    def _warm_ways_buckets(self, args, kw) -> None:
+        """Compile the fused dispatch at every way bucket the SSD level
+        can reach, the first time any controller meets this window
+        bucket, so that a later change of bucket compiles nothing.
+
+        No VM holds more than ``ceil(ssd_capacity / num_sets)`` ways, so
+        that bounds the buckets. Each is a no-op dispatch (every row idle,
+        ``wlen == 0``) on the interval's own arrays, whose shapes and
+        placement the real calls share; its outputs are dropped. This is
+        set-up work: it runs once per window bucket and process.
+        """
+        from repro.kernels import resolve_interpret
+        from repro.kernels.maintenance import ops as maint_ops
+        gs = self.cfg.geometry_ssd
+        cap = -(-self.cfg.ssd_capacity // gs.num_sets)
+        buckets = maint_ops.ways_buckets_upto(cap, gs.max_ways)
+        key = (tuple(x.shape for x in jax.tree.leaves(args)), buckets,
+               resolve_interpret(None), tuple(sorted(kw.items())))
+        if key in _WARMED_WAYS_BUCKETS:
+            return
+        idle = np.zeros(self._rows, np.int32)
+        for wb in buckets:
+            # one at a time: each holds a copy of the state and the table
+            jax.block_until_ready(maint_ops.maintenance_interval(
+                *args, idle, idle, self.t, ways_bucket=wb, **kw))
+        _WARMED_WAYS_BUCKETS.add(key)
 
     def _maintain_staged(self, chunks: list[Trace | None]) -> None:
         """Staged batched maintenance (host trackers + separate vmapped

@@ -14,7 +14,10 @@ promotion-queue build -> promote kernel -> background cleaner (age
 cutoff + clean kernel, when ``clean_quota > 0``). The post-eviction
 state feeds the promotion stage on device and the post-promotion state
 feeds the cleaner — there is no ``np.asarray(state)`` sync anywhere
-between stages; only the final per-VM counts ever reach the host.
+between stages; only the final per-VM counts ever reach the host. Every
+stage works on the leading ``ways_bucket`` ways of the SSD state (a power
+of two at least the largest active way count), which keeps the queue
+widths and the kernels' loops at the size of the live ways.
 """
 from __future__ import annotations
 
@@ -200,14 +203,44 @@ def promote(state: CacheState, queues, ways, t, *, ts: int = DEFAULT_TS,
 # the fused per-interval dispatch
 # ---------------------------------------------------------------------------
 
+def ways_bucket_of(ways, max_ways: int) -> int:
+    """The way bucket of the fused dispatch for host ``ways``: the next
+    power of two of the largest active way count (at least 1), clipped to
+    the geometry's ``max_ways``."""
+    top = int(np.max(np.asarray(ways), initial=0))
+    return min(_next_pow2(max(top, 1)), max_ways)
+
+
+def ways_buckets_upto(max_active: int, max_ways: int) -> tuple[int, ...]:
+    """Every bucket :func:`ways_bucket_of` gives while no VM has more
+    than ``max_active`` ways: the powers of two below the top bucket,
+    then the top bucket."""
+    top = ways_bucket_of([max_active], max_ways)
+    return tuple(b for b in (1 << i for i in range(top.bit_length()))
+                 if b < top) + (top,)
+
+
 @functools.partial(
     jax.jit, static_argnames=("evict_frac", "decay", "clean_quota", "ts",
-                              "qc", "interpret"))
+                              "qc", "interpret", "ways_bucket"))
 def _maintenance_impl(ssd: CacheState, table: pop.PopularityTable,
                       dist, served, waddr, wlen, ways, t, *,
                       evict_frac: float, decay: float, clean_quota: int,
-                      ts: int, qc: int, interpret: bool):
-    v, s, w = ssd.tags.shape
+                      ts: int, qc: int, interpret: bool,
+                      ways_bucket: int | None = None):
+    full = ssd
+    v, s, w = full.tags.shape
+    # Every stage below runs on the leading `ways_bucket` ways only, and
+    # the slice is written back at the end. That is exact for any bucket
+    # >= max(ways): resize clears every way at or above a VM's `ways` and
+    # nothing places a block there, so the tail holds no block and no
+    # stage changes it. The slice's flattened (set, way) order is the
+    # full state's, so the eviction candidates' stable ties break as
+    # before, and the cleaner's (lru, set * w + way) key keeps its order,
+    # since the kernel and _clean_cutoffs index in the same frame.
+    if ways_bucket is not None and ways_bucket < w:
+        w = ways_bucket
+        ssd = CacheState(*(x[:, :, :w] for x in full))
     nval = jnp.asarray(wlen, jnp.int32)
     live = nval > 0
     ways = jnp.asarray(ways, jnp.int32)
@@ -220,7 +253,7 @@ def _maintenance_impl(ssd: CacheState, table: pop.PopularityTable,
 
     # 2) eviction queue (bottom-frac of residents when >= 90% full) ->
     #    evict kernel. Its valid entries are a prefix of at most
-    #    ceil(frac * S * W) (the table's own f32 rounding), so the
+    #    ceil(frac * S * w) (the table's own f32 rounding), so the
     #    truncation below drops only -1 padding.
     equeue, eqlen = pop.table_least_popular(table, ssd.tags, ways, alloc,
                                             live, evict_frac)
@@ -254,18 +287,22 @@ def _maintenance_impl(ssd: CacheState, table: pop.PopularityTable,
     else:
         cleaned = jnp.zeros(v, jnp.int32)
         dirty_left = jnp.sum(ssd.dirty & active, axis=(1, 2)).astype(jnp.int32)
+    if w < full.tags.shape[2]:
+        ssd = CacheState(*(jax.lax.dynamic_update_slice(f, x, (0, 0, 0))
+                           for f, x in zip(full, ssd)))
     return (ssd, table, flushed, promoted, eqlen, pqlen, drops, cleaned,
             dirty_left)
 
 
 @functools.lru_cache(maxsize=None)
 def _maintenance_sharded(mesh, evict_frac, decay, clean_quota, ts, qc,
-                         interpret):
+                         interpret, ways_bucket=None):
     """``shard_map`` of :func:`_maintenance_impl` over a VM mesh: each
     device runs the full three-stage maintenance on its own ``[V/d, ...]``
-    block of states/queues. Queue widths depend only on geometry and
-    window bucket (never on V), so per-shard shapes line up and the
-    compiled HLO is collective-free (asserted by the sharding tests)."""
+    block of states/queues. Queue widths depend only on geometry, window
+    bucket and way bucket (never on V; the way bucket is the maximum over
+    all rows), so per-shard shapes line up and the compiled HLO is
+    collective-free (asserted by the sharding tests)."""
     from repro.launch.mesh import vm_spec
     spec = vm_spec(mesh)
 
@@ -273,7 +310,7 @@ def _maintenance_sharded(mesh, evict_frac, decay, clean_quota, ts, qc,
         return _maintenance_impl(
             ssd, table, dist, served, waddr, wlen, ways, t,
             evict_frac=evict_frac, decay=decay, clean_quota=clean_quota,
-            ts=ts, qc=qc, interpret=interpret)
+            ts=ts, qc=qc, interpret=interpret, ways_bucket=ways_bucket)
 
     return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(spec,) * 8, out_specs=(spec,) * 9,
@@ -285,7 +322,8 @@ def maintenance_interval(ssd: CacheState, table: pop.PopularityTable,
                          evict_frac: float, decay: float,
                          clean_quota: int = 0,
                          ts: int = DEFAULT_TS, qc: int = DEFAULT_QC,
-                         interpret: bool | None = None, mesh=None):
+                         interpret: bool | None = None, mesh=None,
+                         ways_bucket: int | None = None):
     """One interval of ETICA maintenance for all VMs, fused.
 
     Args:
@@ -315,8 +353,24 @@ def maintenance_interval(ssd: CacheState, table: pop.PopularityTable,
     ``mesh`` splits the VM axis over a 1-d device mesh (V divisible by
     the mesh size; pad with dead ``wlen == 0`` VMs first): the whole
     dispatch runs shard-local with bit-identical per-VM results.
+
+    ``ways_bucket`` (static) is how many leading ways the dispatch works
+    on; every way at or above a VM's ``ways`` must hold no block, as
+    resize leaves it. Any bucket from ``max(ways)`` to ``W`` gives the
+    same result. ``None`` takes :func:`ways_bucket_of` of host ``ways``
+    (a numpy array or a sequence) and ``W`` for device ``ways``, which it
+    does not read back.
     """
     interpret = resolve_interpret(interpret)
+    w = int(ssd.tags.shape[2])
+    host_ways = not isinstance(ways, jax.Array)
+    if ways_bucket is None:
+        ways_bucket = ways_bucket_of(ways, w) if host_ways else w
+    if not 1 <= ways_bucket <= w:
+        raise ValueError(f"ways_bucket {ways_bucket} outside [1, {w}]")
+    if host_ways and int(np.max(np.asarray(ways), initial=0)) > ways_bucket:
+        raise ValueError(f"ways_bucket {ways_bucket} is below the largest "
+                         f"active way count {int(np.max(ways))}")
     args = (ssd, table, jnp.asarray(dist, jnp.int32),
             jnp.asarray(served, bool), jnp.asarray(waddr, jnp.int32),
             jnp.asarray(wlen, jnp.int32), jnp.asarray(ways, jnp.int32),
@@ -326,10 +380,11 @@ def maintenance_interval(ssd: CacheState, table: pop.PopularityTable,
         require_vm_divisible(int(ssd.tags.shape[0]), mesh)
         return _maintenance_sharded(
             mesh, float(evict_frac), float(decay), int(clean_quota), ts, qc,
-            interpret)(*args)
+            interpret, ways_bucket)(*args)
     return _maintenance_impl(
         *args, evict_frac=float(evict_frac), decay=float(decay),
-        clean_quota=int(clean_quota), ts=ts, qc=qc, interpret=interpret)
+        clean_quota=int(clean_quota), ts=ts, qc=qc, interpret=interpret,
+        ways_bucket=ways_bucket)
 
 
 # ---------------------------------------------------------------------------

@@ -406,8 +406,9 @@ def collect_telemetry(rec, prefix: str = "etica",
                       label: str = "vm") -> list:
     """Metric families from a :class:`~repro.runtime.telemetry
     .TelemetryRecorder`: the dispatch-span wall-clock histograms, the
-    journal row counter, and the *last* recorded interval's request/hit
-    deltas and LBICA-style overload flags (``{prefix}_overloaded``).
+    fused maintenance dispatches per way bucket, the journal row counter,
+    and the *last* recorded interval's request/hit deltas and LBICA-style
+    overload flags (``{prefix}_overloaded``).
     ``label`` names the per-entity axis (``vm`` for the block-cache
     controllers, ``tenant`` for the serving manager)."""
     hist = Metric(f"{prefix}_dispatch_seconds", "histogram",
@@ -419,6 +420,11 @@ def collect_telemetry(rec, prefix: str = "etica",
                  HistogramValue(tuple(s.buckets),
                                 tuple(int(c) for c in s.counts),
                                 float(s.total)))
+    buckets = Metric(f"{prefix}_maintenance_ways_bucket_total", "counter",
+                     "Fused maintenance dispatches per way bucket (the "
+                     "leading SSD ways the dispatch works on).")
+    for wb in sorted(rec.ways_buckets):
+        buckets.add({"ways_bucket": str(wb)}, rec.ways_buckets[wb])
     ivals = Metric(f"{prefix}_telemetry_intervals_total", "counter",
                    "Interval samples appended to the telemetry journal.")
     ivals.add({}, rec.journal.total)
@@ -438,7 +444,7 @@ def collect_telemetry(rec, prefix: str = "etica",
             vec, values = _vector(row[col])
             for i, v in enumerate(values):
                 metric.add({label: str(i)} if vec else {}, float(v))
-    return [hist, ivals, i_req, i_hit, over]
+    return [hist, buckets, ivals, i_req, i_hit, over]
 
 
 def render_cache(cache, prefix: str = "etica") -> str:

@@ -352,6 +352,9 @@ class TelemetryRecorder:
         self.journal = Journal(window=window, spill=spill)
         self.span_timing = bool(span_timing)
         self.spans: dict[str, SpanStats] = {}
+        # fused maintenance dispatches per way bucket (the leading SSD
+        # ways they work on), counted by the controller per interval
+        self.ways_buckets: collections.Counter = collections.Counter()
         self.overload = overload if overload is not None else OverloadConfig()
         self._prev: dict[str, np.ndarray] = {}
         self._ov_hits = collections.deque(maxlen=self.overload.window)

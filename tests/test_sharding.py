@@ -208,6 +208,39 @@ def test_maintenance_sharded_bit_identical_and_local():
         jnp.asarray(t), label="maintenance")
 
 
+def test_maintenance_sharded_ways_bucket_bit_identical_and_local():
+    """The sharded dispatch on the leading ways (bucket 2: the largest
+    active way count over every row) == the single-device full width."""
+    addr, is_write = _requests(seed=8, n=64)
+    ways = np.tile(np.asarray([0, 2, 1, 1], np.int32), V)[:V]
+    _, ssd, _, _ = simulate_two_level_batch(
+        addr, is_write, make_cache_batch(V, S, W), make_cache_batch(V, S, W),
+        np.full(V, 1, np.int32), ways, mode="full")
+    rng = np.random.default_rng(18)
+    n = 48
+    waddr = rng.integers(0, 24, (V, n)).astype(np.int32)
+    dist = rng.integers(-1, 8, (V, n)).astype(np.int32)
+    served = (rng.random((V, n)) < 0.5) & (dist >= 0)
+    wlen = rng.integers(1, n + 1, V).astype(np.int32)
+    wlen[0] = 0                      # an idle VM rides along untouched
+    t = rng.integers(1, 9, V).astype(np.int32)
+    table = table_init(V, 64)
+    kw = dict(evict_frac=0.25, decay=0.5, clean_quota=2, interpret=True)
+    assert maint_ops.ways_bucket_of(ways, W) == 2
+    ref = maint_ops.maintenance_interval(ssd, table, dist, served, waddr,
+                                         wlen, ways, t, ways_bucket=W, **kw)
+    got = maint_ops.maintenance_interval(ssd, table, dist, served, waddr,
+                                         wlen, ways, t, mesh=MESH, **kw)
+    _assert_tree_equal(ref, got, "fused maintenance, way bucket 2")
+    _assert_local(
+        maint_ops._maintenance_sharded(MESH, 0.25, 0.5, 2,
+                                       maint_ops.DEFAULT_TS,
+                                       maint_ops.DEFAULT_QC, True, 2),
+        ssd, table, jnp.asarray(dist), jnp.asarray(served, bool),
+        jnp.asarray(waddr), jnp.asarray(wlen), jnp.asarray(ways),
+        jnp.asarray(t), label="maintenance, way bucket 2")
+
+
 # ---------------------------------------------------------------------------
 # sizing / POD reductions (manual per-device dispatch)
 # ---------------------------------------------------------------------------

@@ -526,6 +526,7 @@ def _demo_recorder():
 
 def test_collect_telemetry_families():
     rec = _demo_recorder()
+    rec.ways_buckets.update([2, 2, 4])
     fams = metrics.parse_exposition(
         metrics.render(metrics.collect_telemetry(rec)))
     assert fams["etica_dispatch_seconds"]["type"] == "histogram"
@@ -536,6 +537,10 @@ def test_collect_telemetry_families():
     assert fams["etica_overloaded"]["samples"][(("vm", "1"),)] == 0.0
     assert ("count", ("span", "demo")) in \
         fams["etica_dispatch_seconds"]["samples"]
+    wb = fams["etica_maintenance_ways_bucket_total"]
+    assert wb["type"] == "counter"
+    assert wb["samples"] == {(("ways_bucket", "2"),): 2.0,
+                             (("ways_bucket", "4"),): 1.0}
 
 
 def test_live_scrape_round_trips():

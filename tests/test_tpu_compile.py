@@ -108,6 +108,29 @@ def test_fused_maintenance(spec):
                      spec((V, WINDOW)), spec((V,)), spec((V,)), spec((V,)))
 
 
+@pytest.mark.parametrize("ways_bucket", [1, 2])
+def test_fused_maintenance_ways_bucket(spec, ways_bucket):
+    """The fused dispatch on the leading ways: one- and two-way strips
+    (a lane axis far under 128) for the three kernels."""
+    from repro.core import popularity as pop
+    from repro.core.simulator import CacheState
+    from repro.kernels.maintenance import ops
+
+    def step(tags, lru, dirty, taddr, tval, dist, served, waddr, wlen, ways,
+             t):
+        return ops._maintenance_impl(
+            CacheState(tags, lru, dirty), pop.PopularityTable(taddr, tval),
+            dist, served, waddr, wlen, ways, t, evict_frac=0.05, decay=0.5,
+            clean_quota=4, ts=ops.DEFAULT_TS, qc=ops.DEFAULT_QC,
+            interpret=False, ways_bucket=ways_bucket)
+
+    st = spec((V, S, W))
+    _compiles_kernel(step, st, st, spec((V, S, W), jnp.bool_),
+                     spec((V, POP_K)), spec((V, POP_K), jnp.float32),
+                     spec((V, WINDOW)), spec((V, WINDOW), jnp.bool_),
+                     spec((V, WINDOW)), spec((V,)), spec((V,)), spec((V,)))
+
+
 def test_sizing_reduction(spec):
     from repro.kernels.reuse_distance import ops
     n = 16_384
