@@ -292,6 +292,7 @@ class EticaCache:
         # logs, trackers) stay at the real VM count.
         self._rows, self._sharding = _mesh_setup(
             cfg.mesh, num_vms, cfg.batched, cfg.classifier)
+        self._shards = 1 if cfg.mesh is None else cfg.mesh.size
         rows = self._rows
         gd, gs = cfg.geometry_dram, cfg.geometry_ssd
         if cfg.batched:
@@ -390,7 +391,8 @@ class EticaCache:
                     capacity: int, cls_subs: list[np.ndarray] | None = None,
                     *, level: str):
         grid = _mrc_grid(geom, self.cfg.mrc_points)
-        with self.telemetry.span("sizing", level=level) as sp:
+        with self.telemetry.span("sizing", level=level,
+                                 shards=self._shards) as sp:
             demands = np.zeros(self.num_vms, np.int64)
             curves = np.zeros((self.num_vms, grid.size))
             addrs = [np.asarray(s.addr) for s in subs]
@@ -419,7 +421,8 @@ class EticaCache:
                     dists = reuse.pod_distances_batch(
                         addrs + [np.empty(0, np.int32)] * pad,
                         writes + [np.empty(0, bool)] * pad,
-                        policy, mesh=self.cfg.mesh)[: self.num_vms]
+                        policy, mesh=self.cfg.mesh,
+                        telemetry=self.telemetry)[: self.num_vms]
                 else:
                     dists = reuse.pod_distances_batch(addrs, writes, policy)
                 sp.ready(dists)
@@ -563,7 +566,8 @@ class EticaCache:
         wb = maint_ops.ways_bucket_of(self.ways_ssd,
                                       cfg.geometry_ssd.max_ways)
         self.telemetry.ways_buckets[wb] += 1
-        with self.telemetry.span("maintenance", ways_bucket=wb) as sp:
+        with self.telemetry.span("maintenance", ways_bucket=wb,
+                                 shards=self._shards) as sp:
             # batched TRD decomposition (same bucketing as
             # trd_distances_batch) — results stay on device and feed the
             # fused dispatch directly. ALL VMs ride as rows (idle ones
@@ -575,7 +579,11 @@ class EticaCache:
                                          list(range(self._rows)), lens)
             if cfg.mesh is not None:
                 r = reuse._decompose_sharded(cfg.mesh, amat, wmat,
-                                             Policy.WB, False, 256)
+                                             Policy.WB, False, 256,
+                                             self.telemetry)
+                # the gathered TRD distances go up again, to the shards
+                self.telemetry.shard_host_bytes += (r.dist.nbytes
+                                                    + r.served.nbytes)
             else:
                 r = reuse._decompose_vmapped(amat, wmat, policy=Policy.WB,
                                              sizing_reads_only=False,

@@ -304,7 +304,11 @@ def _decompose_vmapped(amat, wmat, policy, sizing_reads_only, chunk):
 # resize, stats aggregation) live in ``core.simulator`` /
 # ``kernels.maintenance``.
 
-def _decompose_sharded(mesh, amat, wmat, policy, sizing_reads_only, chunk):
+def _decompose_sharded(mesh, amat, wmat, policy, sizing_reads_only, chunk,
+                       telemetry=None):
+    """Each device decomposes its row block; the blocks are gathered to
+    host numpy, and ``telemetry.shard_host_bytes`` (where a recorder is
+    given) counts the bytes gathered."""
     from ..launch.mesh import device_row_blocks
     parts = []
     for dev, rows in device_row_blocks(amat.shape[0], mesh):
@@ -314,9 +318,13 @@ def _decompose_sharded(mesh, amat, wmat, policy, sizing_reads_only, chunk):
                                         sizing_reads_only=sizing_reads_only,
                                         chunk=chunk))
     parts = sync("decompose", parts)
-    return DistResult(*[
+    out = DistResult(*[
         np.concatenate([getattr(p, f) for p in parts], axis=0)
         for f in ("dist", "served", "touch")])
+    if telemetry is not None:
+        telemetry.shard_host_bytes += sum(x.nbytes for x in
+                                          (out.dist, out.served, out.touch))
+    return out
 
 
 def _sizing_sharded(mesh, amat, wmat, nvec, grid, kind, chunk):
@@ -340,7 +348,8 @@ def _require_divisible(num_rows: int, mesh) -> None:
 
 
 def _distances_batch(addrs, writes, policy: Policy, sizing_reads_only: bool,
-                     chunk: int, mesh=None) -> list[DistResult | None]:
+                     chunk: int, mesh=None,
+                     telemetry=None) -> list[DistResult | None]:
     """Decompose many traces in ONE vmapped dispatch.
 
     ``addrs``/``writes`` are ragged per-VM request lists; rows are padded
@@ -353,7 +362,9 @@ def _distances_batch(addrs, writes, policy: Policy, sizing_reads_only: bool,
     device decomposes its own block shard-locally. Empty rows are then
     packed too (as pure-pad rows, which the row-wise computation treats
     identically), so the row count — which must be divisible by the mesh
-    size — lines up with the shard layout.
+    size — lines up with the shard layout, and ``telemetry`` (a
+    :class:`~repro.runtime.telemetry.TelemetryRecorder`) counts the bytes
+    gathered from the shards.
     """
     lens = [int(np.shape(a)[0]) for a in addrs]
     live = [v for v, n in enumerate(lens) if n > 0]
@@ -364,7 +375,7 @@ def _distances_batch(addrs, writes, policy: Policy, sizing_reads_only: bool,
         rows = list(range(len(lens)))
         amat, wmat = _pad_rows(addrs, writes, rows, lens)
         r = _decompose_sharded(mesh, amat, wmat, policy,
-                               sizing_reads_only, chunk)
+                               sizing_reads_only, chunk, telemetry)
         idx = rows
     else:
         amat, wmat = _pad_rows(addrs, writes, live, lens)
@@ -382,12 +393,13 @@ def _distances_batch(addrs, writes, policy: Policy, sizing_reads_only: bool,
     return out
 
 
-def pod_distances_batch(addrs, writes, policy: Policy,
-                        chunk: int = 256, mesh=None) -> list[DistResult | None]:
+def pod_distances_batch(addrs, writes, policy: Policy, chunk: int = 256,
+                        mesh=None, telemetry=None) -> list[DistResult | None]:
     """Per-VM :func:`pod_distances` in one vmapped dispatch (ragged input,
     bit-identical per-VM results; empty traces -> ``None``). ``mesh``
     shards the VM rows across devices (shard-local, no collectives)."""
-    return _distances_batch(addrs, writes, policy, True, chunk, mesh=mesh)
+    return _distances_batch(addrs, writes, policy, True, chunk, mesh=mesh,
+                            telemetry=telemetry)
 
 
 def trd_distances_batch(addrs, writes,

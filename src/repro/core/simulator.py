@@ -839,6 +839,9 @@ def resize_levels(dram: CacheState, ssd: CacheState, old_dram, new_dram,
 # cross-device traffic in a sharded controller run is
 # :func:`aggregate_stats_sharded`'s psum. Builders are lru-cached on
 # (mesh, statics) so controller intervals reuse compiled executables.
+# Each jitted function is named after its public entry, so the device
+# trace shows ``jit_simulate_two_level_sharded`` and so on, apart from the
+# single-device programs and from each other.
 
 def _vm_io(mesh):
     from ..launch.mesh import require_vm_divisible, vm_spec
@@ -849,15 +852,16 @@ def _vm_io(mesh):
 def _two_level_sharded(mesh, mode):
     spec, _ = _vm_io(mesh)
 
-    def body(addr, is_write, dram, ssd, ways_dram, ways_ssd, t0):
+    def simulate_two_level_sharded(addr, is_write, dram, ssd, ways_dram,
+                                   ways_ssd, t0):
         return jax.vmap(
             lambda a, w, dr, ss, wd, ws, tt: _simulate_two_level(
                 a, w, dr, ss, wd, ws, mode, tt)
         )(addr, is_write, dram, ssd, ways_dram, ways_ssd, t0)
 
     return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(spec,) * 7, out_specs=spec,
-        check_vma=False))
+        simulate_two_level_sharded, mesh=mesh, in_specs=(spec,) * 7,
+        out_specs=spec, check_vma=False))
 
 
 def simulate_two_level_sharded(addr, is_write, dram: CacheState,
@@ -879,13 +883,14 @@ def _single_level_sharded(mesh):
     from jax.sharding import PartitionSpec
     spec, _ = _vm_io(mesh)
 
-    def body(addr, is_write, state, ways_active, flags, t_cache, t0):
+    def simulate_single_level_sharded(addr, is_write, state, ways_active,
+                                      flags, t_cache, t0):
         return jax.vmap(
             _simulate_single_level, in_axes=(0, 0, 0, 0, 0, None, 0)
         )(addr, is_write, state, ways_active, flags, t_cache, t0)
 
     return jax.jit(jax.shard_map(
-        body, mesh=mesh,
+        simulate_single_level_sharded, mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec, PartitionSpec(), spec),
         out_specs=spec, check_vma=False))
 
@@ -911,14 +916,15 @@ def simulate_single_level_sharded(addr, is_write, state: CacheState,
 def _resize_levels_sharded(mesh):
     spec, _ = _vm_io(mesh)
 
-    def body(dram, ssd, old_dram, new_dram, old_ssd, new_ssd):
+    def resize_levels_sharded(dram, ssd, old_dram, new_dram, old_ssd,
+                              new_ssd):
         dram, fl_d = jax.vmap(resize)(dram, old_dram, new_dram)
         ssd, fl_s = jax.vmap(resize)(ssd, old_ssd, new_ssd)
         return dram, ssd, fl_d, fl_s
 
     return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(spec,) * 6, out_specs=spec,
-        check_vma=False))
+        resize_levels_sharded, mesh=mesh, in_specs=(spec,) * 6,
+        out_specs=spec, check_vma=False))
 
 
 def resize_levels_sharded(dram: CacheState, ssd: CacheState, old_dram,
@@ -935,9 +941,13 @@ def resize_levels_sharded(dram: CacheState, ssd: CacheState, old_dram,
 @functools.lru_cache(maxsize=None)
 def _resize_batch_sharded(mesh):
     spec, _ = _vm_io(mesh)
+
+    def resize_batch_sharded(st, old, new):
+        return jax.vmap(resize)(st, old, new)
+
     return jax.jit(jax.shard_map(
-        lambda st, old, new: jax.vmap(resize)(st, old, new),
-        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False))
+        resize_batch_sharded, mesh=mesh, in_specs=(spec,) * 3,
+        out_specs=spec, check_vma=False))
 
 
 def resize_batch_sharded(state: CacheState, old_ways, new_ways, mesh):
@@ -955,13 +965,13 @@ def _aggregate_stats_sharded(mesh):
     spec, _ = _vm_io(mesh)
     ax = mesh.axis_names[0]
 
-    def body(st):
+    def aggregate_stats_sharded(st):
         return jax.tree_util.tree_map(
             lambda x: jax.lax.psum(jnp.sum(x, axis=0), ax), st)
 
     return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(spec,), out_specs=PartitionSpec(),
-        check_vma=False))
+        aggregate_stats_sharded, mesh=mesh, in_specs=(spec,),
+        out_specs=PartitionSpec(), check_vma=False))
 
 
 def aggregate_stats_sharded(stats: Stats, mesh) -> Stats:

@@ -299,22 +299,24 @@ def _maintenance_sharded(mesh, evict_frac, decay, clean_quota, ts, qc,
                          interpret, ways_bucket=None):
     """``shard_map`` of :func:`_maintenance_impl` over a VM mesh: each
     device runs the full three-stage maintenance on its own ``[V/d, ...]``
-    block of states/queues. Queue widths depend only on geometry, window
-    bucket and way bucket (never on V; the way bucket is the maximum over
-    all rows), so per-shard shapes line up and the compiled HLO is
-    collective-free (asserted by the sharding tests)."""
+    block of states/queues, as the program ``jit_maintenance_sharded``.
+    Queue widths depend only on geometry, window bucket and way bucket
+    (never on V; the way bucket is the maximum over all rows), so
+    per-shard shapes line up and the compiled HLO is collective-free
+    (asserted by the sharding tests)."""
     from repro.launch.mesh import vm_spec
     spec = vm_spec(mesh)
 
-    def body(ssd, table, dist, served, waddr, wlen, ways, t):
+    def maintenance_sharded(ssd, table, dist, served, waddr, wlen, ways,
+                            t):
         return _maintenance_impl(
             ssd, table, dist, served, waddr, wlen, ways, t,
             evict_frac=evict_frac, decay=decay, clean_quota=clean_quota,
             ts=ts, qc=qc, interpret=interpret, ways_bucket=ways_bucket)
 
     return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(spec,) * 8, out_specs=(spec,) * 9,
-        check_vma=False))
+        maintenance_sharded, mesh=mesh, in_specs=(spec,) * 8,
+        out_specs=(spec,) * 9, check_vma=False))
 
 
 def maintenance_interval(ssd: CacheState, table: pop.PopularityTable,

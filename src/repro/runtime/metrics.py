@@ -406,7 +406,8 @@ def collect_telemetry(rec, prefix: str = "etica",
                       label: str = "vm") -> list:
     """Metric families from a :class:`~repro.runtime.telemetry
     .TelemetryRecorder`: the dispatch-span wall-clock histograms, the
-    fused maintenance dispatches per way bucket, the journal row counter,
+    fused maintenance dispatches per way bucket, the bytes the sharded
+    path moves through the host, the journal row counter,
     and the *last* recorded interval's request/hit deltas and LBICA-style
     overload flags (``{prefix}_overloaded``).
     ``label`` names the per-entity axis (``vm`` for the block-cache
@@ -425,6 +426,11 @@ def collect_telemetry(rec, prefix: str = "etica",
                      "leading SSD ways the dispatch works on).")
     for wb in sorted(rec.ways_buckets):
         buckets.add({"ways_bucket": str(wb)}, rec.ways_buckets[wb])
+    host = Metric(f"{prefix}_shard_host_bytes_total", "counter",
+                  "Bytes the VM-sharded path moves through the host: reuse "
+                  "decompositions gathered from the shards, and the one "
+                  "uploaded again into the sharded maintenance.")
+    host.add({}, rec.shard_host_bytes)
     ivals = Metric(f"{prefix}_telemetry_intervals_total", "counter",
                    "Interval samples appended to the telemetry journal.")
     ivals.add({}, rec.journal.total)
@@ -444,7 +450,7 @@ def collect_telemetry(rec, prefix: str = "etica",
             vec, values = _vector(row[col])
             for i, v in enumerate(values):
                 metric.add({label: str(i)} if vec else {}, float(v))
-    return [hist, buckets, ivals, i_req, i_hit, over]
+    return [hist, buckets, host, ivals, i_req, i_hit, over]
 
 
 def render_cache(cache, prefix: str = "etica") -> str:

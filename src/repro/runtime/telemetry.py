@@ -355,6 +355,10 @@ class TelemetryRecorder:
         # fused maintenance dispatches per way bucket (the leading SSD
         # ways they work on), counted by the controller per interval
         self.ways_buckets: collections.Counter = collections.Counter()
+        # bytes of the mesh-only host route: the reuse decompositions the
+        # shards hand back to the host, and the one the sharded
+        # maintenance takes up again (0 on a single device)
+        self.shard_host_bytes = 0
         self.overload = overload if overload is not None else OverloadConfig()
         self._prev: dict[str, np.ndarray] = {}
         self._ov_hits = collections.deque(maxlen=self.overload.window)

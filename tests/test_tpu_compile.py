@@ -162,3 +162,48 @@ def test_count_between_and_popularity(spec):
         lambda d, s, g: popularity(d, s, g, 3000, np.float32(4096.0),
                                    interpret=False),
         spec((n,)), spec((n,), jnp.bool_), spec((n,)))
+
+
+@pytest.mark.parametrize("program", ["simulate_two_level_sharded",
+                                     "maintenance_sharded"])
+def test_sharded_programs_at_consolidation_geometry(topo, program):
+    """The VM-sharded datapath and fused maintenance of the 1,024-VM
+    consolidation over the four chips of a v5e 2x2 host: 256 rows a chip,
+    1024 x 64 state a VM, 32,768 popularity slots a VM. Each compiles
+    under its own name, the maintenance with its kernels, and fits a
+    chip's 16 GB."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.core import simulator as sim
+    from repro.core.simulator import CacheState
+    from repro.kernels.maintenance import ops
+    mesh = Mesh(np.asarray(topo.devices), ("vm",))
+    rows = NamedSharding(mesh, PartitionSpec("vm"))
+    vms = 1024
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rows)
+
+    st = CacheState(spec((vms, S, W)), spec((vms, S, W)),
+                    spec((vms, S, W), jnp.bool_))
+    z = spec((vms,))
+    if program == "simulate_two_level_sharded":
+        fn = sim._two_level_sharded(mesh, "full")
+        args = (spec((vms, 1000)), spec((vms, 1000), jnp.bool_), st, st,
+                z, z, z)
+    else:
+        from repro.core import popularity as pop
+        fn = ops._maintenance_sharded(mesh, 0.05, 0.5, 0, ops.DEFAULT_TS,
+                                      ops.DEFAULT_QC, False, 8)
+        table = pop.PopularityTable(spec((vms, 32_768)),
+                                    spec((vms, 32_768), jnp.float32))
+        args = (st, table, spec((vms, WINDOW)),
+                spec((vms, WINDOW), jnp.bool_), spec((vms, WINDOW)), z, z, z)
+    lowered = fn.lower(*args)
+    assert f"module @jit_{program} " in lowered.as_text()
+    compiled = lowered.compile()
+    if program == "maintenance_sharded":
+        assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16e9
