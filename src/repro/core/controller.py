@@ -393,8 +393,6 @@ class EticaCache:
         grid = _mrc_grid(geom, self.cfg.mrc_points)
         with self.telemetry.span("sizing", level=level,
                                  shards=self._shards) as sp:
-            demands = np.zeros(self.num_vms, np.int64)
-            curves = np.zeros((self.num_vms, grid.size))
             addrs = [np.asarray(s.addr) for s in subs]
             writes = [np.asarray(s.is_write) for s in subs]
             wts = None
@@ -412,37 +410,47 @@ class EticaCache:
                         writes[v] = writes[v][keep]
                         w_req = w_req[keep]
                     wts.append(w_req)
-            if self.cfg.batched:
-                # all VMs' POD decompositions in one vmapped dispatch
-                # (with a mesh: dead-VM rows pad to the sharded row count
-                # and each device decomposes its own block)
-                if self.cfg.mesh is not None:
-                    pad = self._rows - self.num_vms
-                    dists = reuse.pod_distances_batch(
-                        addrs + [np.empty(0, np.int32)] * pad,
-                        writes + [np.empty(0, bool)] * pad,
-                        policy, mesh=self.cfg.mesh,
-                        telemetry=self.telemetry)[: self.num_vms]
-                else:
-                    dists = reuse.pod_distances_batch(addrs, writes, policy)
-                sp.ready(dists)
+            if self.cfg.batched and wts is None:
+                # every VM's POD demand and hit counts in one reduction on
+                # the device and one sync (with a mesh: dead-VM rows pad
+                # to the sharded row count and each device reduces its
+                # own block)
+                pad = self._rows - self.num_vms
+                dem, hits, _ = reuse.sizing_metrics_batch(
+                    addrs + [np.empty(0, np.int32)] * pad,
+                    writes + [np.empty(0, bool)] * pad,
+                    reuse.POD_KINDS[policy], grid, mesh=self.cfg.mesh,
+                    telemetry=self.telemetry)
+                sp.ready((dem, hits))
+                demands = np.minimum(dem[: self.num_vms], geom.capacity)
+                lens = np.array([max(len(s), 1) for s in subs], np.float64)
+                curves = hits[: self.num_vms] / lens[:, None]
             else:
-                dists = [reuse.pod_distances(a, w, policy) if a.size
-                         else None for a, w in zip(addrs, writes)]
-            for v, r in enumerate(dists):
-                if r is None:
-                    continue
-                demands[v] = min(
-                    reuse.demand_blocks(int(sync("demand", r.max))),
-                    geom.capacity)
-                if wts is None:
-                    hits = reuse.hit_counts_at_sizes(r.dist, r.served, grid)
-                    curves[v] = (np.asarray(hits, np.float64)
-                                 / max(len(subs[v]), 1))
+                # the classified route (weighted curves) and the
+                # sequential oracle: per-VM decompositions, reduced here
+                demands = np.zeros(self.num_vms, np.int64)
+                curves = np.zeros((self.num_vms, grid.size))
+                if self.cfg.batched:
+                    dists = reuse.pod_distances_batch(addrs, writes, policy)
+                    sp.ready(dists)
                 else:
-                    hits = reuse.hit_counts_at_sizes_weighted(
-                        r.dist, r.served, grid, wts[v])
-                    curves[v] = hits / max(wts[v].sum(), 1)
+                    dists = [reuse.pod_distances(a, w, policy) if a.size
+                             else None for a, w in zip(addrs, writes)]
+                for v, r in enumerate(dists):
+                    if r is None:
+                        continue
+                    demands[v] = min(
+                        reuse.demand_blocks(int(sync("demand", r.max))),
+                        geom.capacity)
+                    if wts is None:
+                        hits = reuse.hit_counts_at_sizes(r.dist, r.served,
+                                                         grid)
+                        curves[v] = (np.asarray(hits, np.float64)
+                                     / max(len(subs[v]), 1))
+                    else:
+                        hits = reuse.hit_counts_at_sizes_weighted(
+                            r.dist, r.served, grid, wts[v])
+                        curves[v] = hits / max(wts[v].sum(), 1)
         with span("partition"):
             res = _partition(demands, curves, grid, capacity)
             if wts is None:
@@ -450,7 +458,7 @@ class EticaCache:
             else:
                 counts = np.array([w.sum() for w in wts], np.float64)
             alloc = _expand_to_capacity(res.alloc, counts, capacity, geom)
-        return alloc, demands, dists
+        return alloc, demands
 
     # -- maintenance --------------------------------------------------------
     def _alloc_blocks(self, v: int) -> int:
@@ -856,10 +864,10 @@ class EticaCache:
                 self.classifier.classify_subs(subs, self._cls_end,
                                               self._cls_len)
         # 1) POD sizing + PPC partitioning at both levels (§4.3)
-        alloc_d, dem_d, _ = self._size_level(
+        alloc_d, dem_d = self._size_level(
             subs, Policy.RO, cfg.geometry_dram, cfg.dram_capacity,
             cls_subs, level="dram")
-        alloc_s, dem_s, _ = self._size_level(
+        alloc_s, dem_s = self._size_level(
             subs, Policy.WBWO, cfg.geometry_ssd, cfg.ssd_capacity,
             cls_subs, level="ssd")
         self.logs_dram.append(IntervalLog(dem_d, alloc_d))

@@ -327,7 +327,11 @@ def _decompose_sharded(mesh, amat, wmat, policy, sizing_reads_only, chunk,
     return out
 
 
-def _sizing_sharded(mesh, amat, wmat, nvec, grid, kind, chunk):
+def _sizing_sharded(mesh, amat, wmat, nvec, grid, kind, chunk,
+                    telemetry=None):
+    """Each device reduces its row block; the ``(demand, hits, reads)``
+    blocks are gathered to host numpy, and ``telemetry.shard_host_bytes``
+    (where a recorder is given) counts the bytes gathered."""
     from ..launch.mesh import device_row_blocks
     parts = []
     for dev, rows in device_row_blocks(amat.shape[0], mesh):
@@ -338,8 +342,11 @@ def _sizing_sharded(mesh, amat, wmat, nvec, grid, kind, chunk):
         parts.append(_sizing_reduce_vmapped(a, w, n, g,
                                             kind=kind, chunk=chunk))
     parts = sync("sizing", parts)
-    return tuple(np.concatenate([p[i] for p in parts], axis=0)
-                 for i in range(3))
+    out = tuple(np.concatenate([p[i] for p in parts], axis=0)
+                for i in range(3))
+    if telemetry is not None:
+        telemetry.shard_host_bytes += sum(x.nbytes for x in out)
+    return out
 
 
 def _require_divisible(num_rows: int, mesh) -> None:
@@ -348,8 +355,7 @@ def _require_divisible(num_rows: int, mesh) -> None:
 
 
 def _distances_batch(addrs, writes, policy: Policy, sizing_reads_only: bool,
-                     chunk: int, mesh=None,
-                     telemetry=None) -> list[DistResult | None]:
+                     chunk: int) -> list[DistResult | None]:
     """Decompose many traces in ONE vmapped dispatch.
 
     ``addrs``/``writes`` are ragged per-VM request lists; rows are padded
@@ -357,55 +363,34 @@ def _distances_batch(addrs, writes, policy: Policy, sizing_reads_only: bool,
     writes as :func:`_pad_trace` (exact, see above), so per-VM results are
     bit-identical to calling the unbatched functions per VM. Empty rows
     come back as ``None``.
-
-    With ``mesh`` the rows are split over the mesh's VM axis and each
-    device decomposes its own block shard-locally. Empty rows are then
-    packed too (as pure-pad rows, which the row-wise computation treats
-    identically), so the row count — which must be divisible by the mesh
-    size — lines up with the shard layout, and ``telemetry`` (a
-    :class:`~repro.runtime.telemetry.TelemetryRecorder`) counts the bytes
-    gathered from the shards.
     """
     lens = [int(np.shape(a)[0]) for a in addrs]
     live = [v for v, n in enumerate(lens) if n > 0]
-    if not live:
-        return [None] * len(lens)
-    if mesh is not None:
-        _require_divisible(len(lens), mesh)
-        rows = list(range(len(lens)))
-        amat, wmat = _pad_rows(addrs, writes, rows, lens)
-        r = _decompose_sharded(mesh, amat, wmat, policy,
-                               sizing_reads_only, chunk, telemetry)
-        idx = rows
-    else:
-        amat, wmat = _pad_rows(addrs, writes, live, lens)
-        r = sync("decompose", _decompose_vmapped(
-            amat, wmat, policy=policy, sizing_reads_only=sizing_reads_only,
-            chunk=chunk))
-        idx = live
     out: list[DistResult | None] = [None] * len(lens)
-    dist, served, touch = r.dist, r.served, r.touch
-    for i, v in enumerate(idx):
-        if lens[v] > 0:
-            out[v] = DistResult(dist=dist[i, : lens[v]],
-                                served=served[i, : lens[v]],
-                                touch=touch[i, : lens[v]])
+    if not live:
+        return out
+    amat, wmat = _pad_rows(addrs, writes, live, lens)
+    r = sync("decompose", _decompose_vmapped(
+        amat, wmat, policy=policy, sizing_reads_only=sizing_reads_only,
+        chunk=chunk))
+    for i, v in enumerate(live):
+        out[v] = DistResult(dist=r.dist[i, : lens[v]],
+                            served=r.served[i, : lens[v]],
+                            touch=r.touch[i, : lens[v]])
     return out
 
 
-def pod_distances_batch(addrs, writes, policy: Policy, chunk: int = 256,
-                        mesh=None, telemetry=None) -> list[DistResult | None]:
+def pod_distances_batch(addrs, writes, policy: Policy,
+                        chunk: int = 256) -> list[DistResult | None]:
     """Per-VM :func:`pod_distances` in one vmapped dispatch (ragged input,
-    bit-identical per-VM results; empty traces -> ``None``). ``mesh``
-    shards the VM rows across devices (shard-local, no collectives)."""
-    return _distances_batch(addrs, writes, policy, True, chunk, mesh=mesh,
-                            telemetry=telemetry)
+    bit-identical per-VM results; empty traces -> ``None``)."""
+    return _distances_batch(addrs, writes, policy, True, chunk)
 
 
 def trd_distances_batch(addrs, writes,
-                        chunk: int = 256, mesh=None) -> list[DistResult | None]:
+                        chunk: int = 256) -> list[DistResult | None]:
     """Per-VM :func:`trd_distances` in one vmapped dispatch."""
-    return _distances_batch(addrs, writes, Policy.WB, False, chunk, mesh=mesh)
+    return _distances_batch(addrs, writes, Policy.WB, False, chunk)
 
 
 def urd_distances(addr, is_write, chunk: int = 256) -> DistResult:
@@ -482,12 +467,13 @@ def mrc(trace, policy: Policy, sizes: np.ndarray) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Batched sizing reductions (the one-level baselines' metrics, §2.1)
+# Batched sizing reductions (ETICA's POD and the baselines' metrics, §2.1)
 # ---------------------------------------------------------------------------
 #
-# The one-level baselines (ECI-Cache, Centaur, S-CAVE, vCacheShare) size
-# their per-VM partitions from four metrics that are all reductions over
-# the same policy-filtered distance decompositions computed above:
+# ETICA sizes its two levels by POD (§4.3.1); the one-level baselines
+# (ECI-Cache, Centaur, S-CAVE, vCacheShare) size their per-VM partitions
+# from four more metrics. All are reductions over the same policy-filtered
+# distance decompositions computed above:
 #
 #   kind               demand (blocks)              hit-curve channel
 #   ----               ---------------              -----------------
@@ -495,14 +481,18 @@ def mrc(trace, policy: Policy, sizes: np.ndarray) -> np.ndarray:
 #   "trd"              max TRD + 1                  TRD (WB dist, all re-refs)
 #   "wss"              distinct blocks touched      TRD
 #   "reuse_intensity"  re-referenced read blocks    POD(RO)
+#   "pod_ro"           max POD(RO) + 1              POD(RO)
+#   "pod_wbwo"         max POD(WBWO) + 1            POD(WBWO)
 #
 # URD and TRD share one decomposition (same all-touch distances, different
 # served masks), so each kind costs exactly one O(N^2) distance pass.
 # ``sizing_metrics_batch`` evaluates one metric for many VM sub-traces in
-# ONE vmapped jitted dispatch — the baseline analogue of
-# :func:`pod_distances_batch` — so controllers never loop over VMs.
+# ONE vmapped jitted dispatch, so controllers never loop over VMs.
 
-SIZING_KINDS = ("urd", "trd", "wss", "reuse_intensity")
+SIZING_KINDS = ("urd", "trd", "wss", "reuse_intensity", "pod_ro", "pod_wbwo")
+
+# the sizing kind of ETICA's POD under each level's write policy
+POD_KINDS = {Policy.RO: "pod_ro", Policy.WBWO: "pod_wbwo"}
 
 _SERVED_BIG = np.int32(2**30)  # not-served sentinel (np: shard-safe, see COLD)
 
@@ -523,8 +513,10 @@ def read_count(is_write, n_valid=None) -> jax.Array:
 
 def sizing_policy(kind: str) -> tuple[Policy, bool]:
     """The (policy, sizing_reads_only) decomposition a sizing kind rides."""
-    if kind == "reuse_intensity":
+    if kind in ("reuse_intensity", "pod_ro"):
         return Policy.RO, True
+    if kind == "pod_wbwo":
+        return Policy.WBWO, True
     return Policy.WB, False
 
 
@@ -578,7 +570,7 @@ def _sizing_reduce_vmapped(amat, wmat, nvec, grid, kind, chunk):
 
 
 def sizing_metrics_batch(addrs, writes, kind: str, grid,
-                         chunk: int = 256, mesh=None
+                         chunk: int = 256, mesh=None, telemetry=None
                          ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Evaluate one sizing metric for many VM sub-traces in ONE dispatch.
 
@@ -593,14 +585,17 @@ def sizing_metrics_batch(addrs, writes, kind: str, grid,
       and int64 ``[V]`` per-VM read counts (for the dynamic write-policy
       choosers) — zero rows for empty traces. Per-VM values are
       bit-identical to evaluating the sequential per-VM closures in
-      :mod:`repro.core.baselines` — the padding is the same never-reused
-      trailing writes as :func:`_pad_trace`, which no real distance window
-      can see, and the WSS distinct-count and read count mask the pad tail
-      explicitly.
+      :mod:`repro.core.baselines` (for the POD kinds: :func:`pod_distances`,
+      :func:`demand_blocks` and :func:`hit_counts_at_sizes` per VM) — the
+      padding is the same never-reused trailing writes as
+      :func:`_pad_trace`, which no real distance window can see, and the
+      WSS distinct-count and read count mask the pad tail explicitly.
 
     With ``mesh`` the VM rows (all of them, empty ones packed as pure-pad
     rows that reduce to zeros) are split over the mesh's VM axis; each
     device runs its own shard-local reduction and no collectives exist.
+    ``telemetry`` (a :class:`~repro.runtime.telemetry.TelemetryRecorder`)
+    then counts the bytes gathered from the shards.
     """
     if kind not in SIZING_KINDS:
         raise ValueError(f"kind must be one of {SIZING_KINDS}, got {kind!r}")
@@ -617,7 +612,8 @@ def sizing_metrics_batch(addrs, writes, kind: str, grid,
         rows = list(range(len(lens)))
         amat, wmat = _pad_rows(addrs, writes, rows, lens)
         nvec = np.array(lens, np.int32)
-        d, h, r = _sizing_sharded(mesh, amat, wmat, nvec, grid, kind, chunk)
+        d, h, r = _sizing_sharded(mesh, amat, wmat, nvec, grid, kind, chunk,
+                                  telemetry)
         demands[:] = np.asarray(d, np.int64)
         hits[:] = np.asarray(h, np.int64)
         reads[:] = np.asarray(r, np.int64)

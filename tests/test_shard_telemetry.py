@@ -5,8 +5,9 @@
   single-device programs and from each other;
 * ``TelemetryRecorder.shard_host_bytes`` (exported as
   ``etica_shard_host_bytes_total``) counts the bytes the mesh-only host
-  route moves: 0 on one device, positive with a mesh, and results are
-  bit-identical either way and with span timing on;
+  route moves: 0 on one device, with a mesh the sizing reductions' and
+  the maintenance's TRD bytes exactly, and results are bit-identical
+  either way and with span timing on;
 * the ``sizing`` and ``maintenance`` spans carry ``shards``.
 
 The mesh spans every visible device (one on CPU CI).
@@ -22,6 +23,8 @@ import numpy as np
 import pytest
 
 from repro.core import EticaCache, EticaConfig, Geometry, interleave
+from repro.core import reuse
+from repro.core.controller import _mrc_grid
 from repro.core import simulator as sim
 from repro.core.simulator import Stats, make_cache_batch, policy_flags
 from repro.core.policies import Policy
@@ -101,12 +104,32 @@ def _run(cfg):
     return cache, [dict(r.stats) for r in res]
 
 
+def _host_bytes(trace, cfg, rows):
+    """What the mesh route moves through the host: per resize window, each
+    level's sizing reduction gathered (int32 demand, ``G`` hit counts and
+    read count a row), and per promotion interval the TRD decomposition
+    gathered (int32 distance, served and touch masks a request slot) and
+    its distances and served mask uploaded again."""
+    g = _mrc_grid(GEO, cfg.mrc_points).size
+    total = 0
+    for w0 in range(0, len(trace), cfg.resize_interval):
+        n = np.bincount(trace.vm[w0:w0 + cfg.resize_interval],
+                        minlength=rows)
+        total += 2 * rows * (g + 2) * 4
+        p = cfg.promo_interval
+        for k in range(-(-int(n.max()) // p)):
+            b = reuse._bucket(int(np.clip(n - k * p, 0, p).max()))
+            total += rows * b * (6 + 5)
+    return total
+
+
 def test_shard_host_bytes_counted_and_results_unchanged():
     plain, want = _run(_cfg())
     assert plain.telemetry.shard_host_bytes == 0
     sharded, got = _run(_cfg(mesh=MESH))
     assert got == want
-    assert sharded.telemetry.shard_host_bytes > 0
+    assert sharded.telemetry.shard_host_bytes == _host_bytes(
+        _mix(), sharded.cfg, 2 * D)
     for a, b in zip((plain.dram, plain.ssd, plain.pop_table),
                     (sharded.dram, sharded.ssd, sharded.pop_table)):
         for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):

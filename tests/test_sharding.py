@@ -264,16 +264,13 @@ def test_sizing_sharded_matches_jnp_and_kernel_routes():
 
 def test_pod_distances_sharded_matches():
     addrs, writes = _ragged(seed=8)
+    lens = [a.shape[0] for a in addrs]
+    amat, wmat = reuse._pad_rows(addrs, writes, list(range(V)), lens)
     for policy in (Policy.WB, Policy.RO, Policy.WBWO):
-        ref = reuse.pod_distances_batch(addrs, writes, policy)
-        got = reuse.pod_distances_batch(addrs, writes, policy, mesh=MESH)
-        for x, y in zip(ref, got):
-            assert (x is None) == (y is None)
-            if x is not None:
-                assert np.array_equal(np.asarray(x.dist),
-                                      np.asarray(y.dist)), policy
-                assert np.array_equal(np.asarray(x.served),
-                                      np.asarray(y.served)), policy
+        ref = reuse._decompose_vmapped(amat, wmat, policy=policy,
+                                       sizing_reads_only=True, chunk=256)
+        got = reuse._decompose_sharded(MESH, amat, wmat, policy, True, 256)
+        _assert_tree_equal(ref, got, policy)
 
 
 def test_device_row_blocks_partition():
